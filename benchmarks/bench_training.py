@@ -13,11 +13,9 @@ Two complementary reproductions:
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
+import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -28,7 +26,9 @@ from benchmarks.common import (BOOSTER, IDEAL_CPU, IDEAL_GPU, csv_row,
 from repro.api import ExecutionPlan
 from repro.core import GBDTConfig, bin_dataset, train
 from repro.data import make_tabular, paper_dataset
+from repro.distributed.trainer import data_parallel_mesh, train_distributed
 from repro.kernels import ops
+from repro.resilience import RecoveryPolicy
 
 STRATS = ("scatter", "scatter_private", "sort", "onehot")
 
@@ -117,91 +117,71 @@ def run_packed_hist(scale: float = 1.0):
     return rows
 
 
-_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+def _timed_distributed(n: int, n_trees: int, depth: int) -> dict:
+    """Fit timings of ``train_distributed`` on a one-device mesh and on a
+    mesh over every device this process has — in this process: a child
+    could not reach a chip the bench already holds."""
+    X, y, cats = make_tabular(n, 20, 0, task="regression", seed=0)
+    data = bin_dataset(X, max_bins=64)
+    cfg = GBDTConfig(n_trees=n_trees, max_depth=depth, learning_rate=0.3)
 
-# timed in a subprocess: the 8-way mesh needs XLA_FLAGS=
-# --xla_force_host_platform_device_count set before jax initializes,
-# which the parent bench process is too late for
-_DIST_CHILD = r"""
-import json, time
-import jax
-from repro.core import GBDTConfig, bin_dataset
-from repro.data import make_tabular
-from repro.distributed.trainer import data_parallel_mesh, train_distributed
-from repro.resilience import RecoveryPolicy
+    def one(cfg, **kw):
+        t0 = time.perf_counter()
+        train_distributed(cfg, data, y, **kw)
+        return time.perf_counter() - t0
 
-n, n_trees, depth = {n}, {n_trees}, {depth}
-X, y, cats = make_tabular(n, 20, 0, task="regression", seed=0)
-data = bin_dataset(X, max_bins=64)
-cfg = GBDTConfig(n_trees=n_trees, max_depth=depth, learning_rate=0.3)
-
-def timed(**kw):
-    # min-of-2: the recovery-overhead lane compares two subprocess-local
-    # timings, so squeeze scheduler noise out of both sides
-    return min(_one(**kw) for _ in range(2))
-
-def _one(**kw):
-    t0 = time.perf_counter()
-    train_distributed(cfg, data, y, **kw)
-    return time.perf_counter() - t0
-
-out = {{}}
-for tag, devs in (("1shard", jax.devices()[:1]), ("8shard", jax.devices())):
-    mesh = data_parallel_mesh(devs)
-    train_distributed(cfg, data, y, mesh=mesh)   # warm: step cached by mesh
-    out[tag] = timed(mesh=mesh)
-# fault-free fit with the recovery machinery armed (divergence sentinels
-# + checkpointable round loop): measures the wrapper's overhead when
-# nothing fails.  Interleave plain/recovery reps on the warm 8-way mesh
-# so the overhead ratio compares adjacent timings, not distant ones
-rec = RecoveryPolicy()
-# longer fits for the overhead pairs: the wrapper cost is per-round, so
-# more rounds raise the signal while per-fit timing jitter stays flat
-cfg = GBDTConfig(n_trees=n_trees * 3, max_depth=depth, learning_rate=0.3)
-train_distributed(cfg, data, y, mesh=mesh)                # warm
-train_distributed(cfg, data, y, mesh=mesh, recovery=rec)  # warm
-plain, guarded = [], []
-for _ in range(5):
-    plain.append(_one(mesh=mesh))
-    guarded.append(_one(mesh=mesh, recovery=rec))
-out["recovery"] = min(guarded)
-# per-pair ratios: adjacent timings share whatever load the host was
-# under, so the ratio cancels drift the raw times cannot
-ratios = sorted(g / p for g, p in zip(guarded, plain))
-out["overhead"] = ratios[len(ratios) // 2]
-out["recovery_trees"] = n_trees * 3
-print(json.dumps(out))
-"""
+    out = {}
+    meshes = {"1shard": jax.devices()[:1]}
+    if len(jax.devices()) > 1:
+        meshes["all"] = jax.devices()
+    for tag, devs in meshes.items():
+        mesh = data_parallel_mesh(devs)
+        one(cfg, mesh=mesh)                  # warm: step cached by mesh
+        # min-of-2 squeezes scheduler noise out of both sides
+        out[tag] = min(one(cfg, mesh=mesh) for _ in range(2))
+    # fault-free fit with the recovery machinery armed (divergence
+    # sentinels + checkpointable round loop): the wrapper's overhead when
+    # nothing fails.  Longer fits raise the per-round signal; plain and
+    # guarded reps interleave on the warm full mesh, so each ratio
+    # compares adjacent timings under the same host load
+    rec = RecoveryPolicy()
+    cfg = GBDTConfig(n_trees=n_trees * 3, max_depth=depth, learning_rate=0.3)
+    one(cfg, mesh=mesh)
+    one(cfg, mesh=mesh, recovery=rec)
+    plain, guarded = [], []
+    for _ in range(5):
+        plain.append(one(cfg, mesh=mesh))
+        guarded.append(one(cfg, mesh=mesh, recovery=rec))
+    out["recovery"] = min(guarded)
+    ratios = sorted(g / p for g, p in zip(guarded, plain))
+    out["overhead"] = ratios[len(ratios) // 2]
+    out["recovery_trees"] = n_trees * 3
+    return out
 
 
 def run_distributed(scale: float = 1.0, depth: int = 5, n_trees: int = 4):
-    """End-to-end ``train_distributed`` rows/sec on a 1-shard vs an
-    8-virtual-device ``("data",)`` mesh.  On a CPU host the 8 "devices"
-    share the same cores, so the scaling row measures the psum +
-    shard_map overhead rather than real speedup — the two rows/sec lanes
+    """End-to-end ``train_distributed`` rows/sec on a 1-shard mesh and,
+    when the process has more than one device, on a ``("data",)`` mesh
+    over all of them (``train_dist_all``, its shard count in ``derived``).
+    On a CPU host (``benchmarks/run.py`` gives it eight host devices) the
+    "devices" share the same cores, so the scaling row measures the psum
+    + shard_map overhead rather than real speedup — the rows/sec lanes
     are what the perf gate tracks."""
     n = max(4000, int(40000 * scale))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(_ROOT, "src"),
-                    env.get("PYTHONPATH", "")) if p)
-    code = _DIST_CHILD.format(n=n, n_trees=n_trees, depth=depth)
-    out = subprocess.run([sys.executable, "-c", code], env=env, cwd=_ROOT,
-                         capture_output=True, text=True, timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError("distributed bench subprocess failed:\n"
-                           + out.stderr[-2000:])
-    timed = json.loads(out.stdout.strip().splitlines()[-1])
+    timed = _timed_distributed(n, n_trees, depth)
     rows, rps = [], {}
-    for tag in ("1shard", "8shard"):
+    for tag in ("1shard", "all"):
+        if tag not in timed:            # one device: no wider mesh
+            continue
         t = timed[tag]
         rps[tag] = n * n_trees / t
+        shards = len(jax.devices()) if tag == "all" else 1
         rows.append(csv_row(f"train_dist_{tag}", t * 1e6,
                             f"rows_per_sec={rps[tag]:.0f};n={n};"
-                            f"n_trees={n_trees}"))
-    rows.append(csv_row("train_dist_scaling", 0.0,
-                        f"x={rps['8shard'] / rps['1shard']:.2f}"))
+                            f"n_trees={n_trees};shards={shards}"))
+    if "all" in rps:
+        rows.append(csv_row("train_dist_scaling", 0.0,
+                            f"x={rps['all'] / rps['1shard']:.2f}"))
     # fault-free recovery-armed fit on the same mesh: the self-healing
     # wrapper (divergence sentinels, checkpointable rounds) must stay
     # within 5% of the plain engine.  The gate is the median of paired
@@ -269,7 +249,7 @@ def run(scale: float = 1.0, max_bins: int = 128):
     rows.extend(run_packed_hist(scale=scale))
     # (d) end-to-end depth-6 trainer: direct vs subtraction + fused rounds
     rows.extend(run_e2e(scale=scale))
-    # (e) the distributed engine: 1-shard vs 8-virtual-device data mesh
+    # (e) the distributed engine: 1-shard vs all-device data mesh
     rows.extend(run_distributed(scale=scale))
     return rows
 

@@ -38,16 +38,6 @@ def _on_tpu() -> bool:
     return _backend() == "tpu"
 
 
-def _pallas_ok(step: str) -> bool:
-    """One-time per-process probe that the ``step`` Pallas kernel
-    launches on this backend (graceful degradation at plan-resolution
-    time).  Lazy import: the kernels layer imports this module at load,
-    so the dependency must stay runtime-only — and ``resolved()`` is
-    never called during that import."""
-    from repro.kernels import ops as _ops
-    return _ops.pallas_available(step, interpret=not _on_tpu())
-
-
 @dataclasses.dataclass(frozen=True)
 class ExecutionPlan:
     """Kernel/strategy/interpret/mesh selection for every GBDT step.
@@ -61,10 +51,11 @@ class ExecutionPlan:
                          depth level per pass over the codes),
                          ``"scan"`` (legacy one-tree-at-a-time lax.scan —
                          kept as the baseline the benchmarks compare
-                         against), ``"pallas"`` (tree-blocked one-hot
+                         against), ``"pallas"`` (tree-blocked compare-walk
                          kernel), or ``"auto"``
     interpret:           run Pallas kernels in interpret mode (None = auto:
-                         interpret everywhere except a real TPU)
+                         interpret everywhere except a real TPU; True is
+                         refused on a TPU, where the kernels compile)
     records_per_block:   Pallas histogram grid — records per kernel block
     fields_per_block:    Pallas histogram grid — fields per kernel block
     trees_per_block:     Pallas batch inference (§III-D) — tree tables
@@ -179,9 +170,10 @@ class ExecutionPlan:
           ``distributed/sharding._legacy_distributed_plan``): no plan
           means scatter histograms regardless of backend; an explicit
           loose ``hist_strategy`` overrides the plan's field; and
-          ``distributed=True`` pins the partition step to the reference
-          kernel — it runs inside shard_map'd local functions where the
-          Pallas path is untested, and the pre-plan code hardcoded it.
+          ``distributed=True`` resolves the partition step to the
+          reference kernel: those field-sharded growers route records on
+          globally sharded arrays, where a Pallas kernel cannot be
+          partitioned (``train_distributed`` runs it per shard).
 
         The result is always :meth:`resolved`.
         """
@@ -205,28 +197,25 @@ class ExecutionPlan:
     def resolved(self) -> "ExecutionPlan":
         """Replace every ``"auto"`` / ``None`` with the backend default.
 
-        On TPU the ``"auto"`` defaults elect the Pallas kernels — but
-        only after a one-time per-process launch probe
-        (:func:`repro.kernels.ops.pallas_available`) confirms each
-        kernel actually lowers on this backend; a broken lowering
-        resolves straight to the jnp twin (graceful degradation at plan
-        time, before the first real dispatch).  Explicit strategy
-        selections are honored unprobed — the dispatch layer still
-        demotes them per call if they fail.
+        On TPU the ``"auto"`` defaults elect the Pallas kernels, compiled
+        (``interpret=False``); elsewhere the jnp paths, with the kernels
+        available in interpret mode for validation.  Nothing is probed:
+        a kernel that fails to compile or launch raises at its dispatch.
+        ``interpret=True`` on a TPU is refused — it would run the kernels
+        as host Python while the plan claims the chip.
         """
         tpu = _on_tpu()
+        if tpu and self.interpret:
+            raise ValueError("interpret=True is refused on a TPU backend: "
+                             "the Pallas kernels compile there "
+                             "(leave interpret=None or pass False)")
         kw = {}
         if self.hist_strategy == "auto":
-            kw["hist_strategy"] = ("pallas_grouped" if tpu and
-                                   _pallas_ok("histogram") else "scatter")
+            kw["hist_strategy"] = "pallas_grouped" if tpu else "scatter"
         if self.partition_strategy == "auto":
-            kw["partition_strategy"] = ("pallas" if tpu and
-                                        _pallas_ok("partition")
-                                        else "reference")
+            kw["partition_strategy"] = "pallas" if tpu else "reference"
         if self.traversal_strategy == "auto":
-            kw["traversal_strategy"] = ("pallas" if tpu and
-                                        _pallas_ok("traversal")
-                                        else "reference")
+            kw["traversal_strategy"] = "pallas" if tpu else "reference"
         if self.interpret is None:
             kw["interpret"] = not tpu
         if self.hist_subtraction is None:

@@ -379,6 +379,18 @@ def _fused_step_key(config: GBDTConfig) -> GBDTConfig:
         traversal_strategy="auto", host_offload_split=False)
 
 
+def shrink(tree: TreeArrays, learning_rate: float) -> TreeArrays:
+    """Fold the shrinkage into the leaves (a tree is then self-contained:
+    predict == sum of tree outputs, XGBoost-style).
+
+    The product is rounded to float32 before anything adds it: the
+    optimization barrier keeps XLA from contracting ``leaf * lr`` with a
+    margin add into one FMA, so a fused program rounds like the host loop
+    and the resume replay, which add the stored leaf."""
+    return tree._replace(leaf_value=jax.lax.optimization_barrier(
+        tree.leaf_value * learning_rate))
+
+
 @functools.lru_cache(maxsize=64)
 def _fused_round_step(config: GBDTConfig, plan: ExecutionPlan, n: int,
                       F: int, n_bins: int, n_eval: Optional[int]):
@@ -409,8 +421,7 @@ def _fused_round_step(config: GBDTConfig, plan: ExecutionPlan, n: int,
             tree = tree_mod.fit_forest(codes, codes_cm, g.T, h.T, **common)
         else:
             tree = tree_mod.fit_tree(codes, codes_cm, g, h, **common)
-        tree = tree._replace(
-            leaf_value=tree.leaf_value * config.learning_rate)
+        tree = shrink(tree, config.learning_rate)
         data = BinnedDataset(codes, codes_cm, is_cat_field, n_bins,
                              None, None)
         delta = (_predict_forest(tree, data, plan) if K is not None
@@ -552,8 +563,7 @@ def train(config: GBDTConfig, data: BinnedDataset, y,
                 max_leaves=config.max_leaves, **common)
         # shrinkage is folded into the stored leaf values so a tree is
         # self-contained (predict == sum of tree outputs, XGBoost-style)
-        tree = tree._replace(
-            leaf_value=tree.leaf_value * config.learning_rate)
+        tree = shrink(tree, config.learning_rate)
         tree = jax.tree.map(jax.block_until_ready, tree)
         t1 = time.perf_counter()
         step_times["binning_split"] += t1 - t0
@@ -1108,8 +1118,7 @@ def train_streaming(config: GBDTConfig, source, binner, y, *,
                     lambda_=config.lambda_, gamma=config.gamma,
                     min_child_weight=config.min_child_weight,
                     plan=kernel_plan)
-                forest = forest._replace(
-                    leaf_value=forest.leaf_value * config.learning_rate)
+                forest = shrink(forest, config.learning_rate)
                 forest = jax.tree.map(jax.block_until_ready, forest)
                 t1 = time.perf_counter()
 

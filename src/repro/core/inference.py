@@ -33,7 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.api.plan import ExecutionPlan
 from repro.core.binning import Binner, BinnedDataset, PackedCodes
 from repro.core.gbdt import GBDTModel
@@ -253,7 +252,7 @@ def sharded_predict(mesh: Mesh, model: GBDTModel, codes, *,
     # replicated per-shard zeros inside predict_ensemble are unvarying;
     # skip the static varying-axes check (the psum makes the output
     # well-defined)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(da, None),) + tuple(P("model") for _ in range(5)),
         out_specs=P(da, None) if K > 1 else P(da), check_vma=False)

@@ -91,12 +91,13 @@ def fit_tree(codes, codes_cm, g, h, *, depth: int, n_bins: int,
     plan = _lift_loose_kwargs(plan, hist_strategy=hist_strategy,
                               partition_strategy=partition_strategy,
                               host_offload_split=host_offload_split)
-    forest = _fit_forest_jit(codes, codes_cm, g[None], h[None], depth=depth,
-                             n_bins=n_bins, missing_bin=missing_bin,
-                             is_cat_field=is_cat_field,
-                             field_mask=field_mask, lambda_=lambda_,
-                             gamma=gamma,
-                             min_child_weight=min_child_weight, plan=plan)
+    forest = _fit_forest_impl(codes, codes_cm, g[None], h[None],
+                              depth=depth, n_bins=n_bins,
+                              missing_bin=missing_bin,
+                              is_cat_field=is_cat_field,
+                              field_mask=field_mask, lambda_=lambda_,
+                              gamma=gamma,
+                              min_child_weight=min_child_weight, plan=plan)
     return TreeArrays(*[a[0] for a in forest])
 
 
@@ -126,11 +127,11 @@ def fit_forest(codes, codes_cm, g, h, *, depth: int, n_bins: int,
     plan = _lift_loose_kwargs(plan, hist_strategy=hist_strategy,
                               partition_strategy=partition_strategy,
                               host_offload_split=host_offload_split)
-    return _fit_forest_jit(codes, codes_cm, g, h, depth=depth,
-                           n_bins=n_bins, missing_bin=missing_bin,
-                           is_cat_field=is_cat_field, field_mask=field_mask,
-                           lambda_=lambda_, gamma=gamma,
-                           min_child_weight=min_child_weight, plan=plan)
+    return _fit_forest_impl(codes, codes_cm, g, h, depth=depth,
+                            n_bins=n_bins, missing_bin=missing_bin,
+                            is_cat_field=is_cat_field, field_mask=field_mask,
+                            lambda_=lambda_, gamma=gamma,
+                            min_child_weight=min_child_weight, plan=plan)
 
 
 @functools.partial(
@@ -143,14 +144,11 @@ def _fit_forest_jit(codes, codes_cm, g, h, *, depth: int, n_bins: int,
     n, F = codes.shape
     K = g.shape[0]
     n_int = 2 ** depth - 1
-    n_leaf = 2 ** depth
 
     feature = jnp.full((K, n_int), -1, jnp.int32)
     threshold = jnp.zeros((K, n_int), jnp.int32)
     is_cat = jnp.zeros((K, n_int), jnp.int32)
     default_left = jnp.zeros((K, n_int), jnp.int32)
-    value_bottom = jnp.zeros((K, n_leaf), jnp.float32)
-    value_set = jnp.zeros((K, n_leaf), bool)
 
     node_ids = jnp.zeros((K, n), jnp.int32)        # per-class vertex ids
     find = (splits_mod.find_best_splits_host if plan.host_offload_split
@@ -159,8 +157,7 @@ def _fit_forest_jit(codes, codes_cm, g, h, *, depth: int, n_bins: int,
     part = jax.vmap(functools.partial(ops.partition_level,
                                       missing_bin=missing_bin, plan=plan))
 
-    state = (feature, threshold, is_cat, default_left, value_bottom,
-             value_set)
+    state = (feature, threshold, is_cat, default_left)
     prev_hist = None
     for level in range(depth):
         nn = 2 ** level
@@ -178,7 +175,7 @@ def _fit_forest_jit(codes, codes_cm, g, h, *, depth: int, n_bins: int,
         # step ② — split decisions + tree-table updates (shared with the
         # chunked grower, which accumulates the same hist across chunks)
         state, best, do_split = _decide_level(
-            hist, level, depth, state, is_cat_field, field_mask, lambda_,
+            hist, level, state, is_cat_field, field_mask, lambda_,
             gamma, min_child_weight, find)
 
         # step ③ — per-class predicate columns from the column-major copy
@@ -191,24 +188,32 @@ def _fit_forest_jit(codes, codes_cm, g, h, *, depth: int, n_bins: int,
                                        (K, nn)), -1),
             best.threshold, best.is_cat, best.default_left)
 
-    feature, threshold, is_cat, default_left, value_bottom, value_set = state
-    value_bottom = _settle_bottom_leaves(g, h, node_ids, value_bottom,
-                                         value_set, n_leaf, lambda_)
+    return state, node_ids
+
+
+def _fit_forest_impl(codes, codes_cm, g, h, *, lambda_, **kw) -> TreeArrays:
+    """The level loop, then the bottom-leaf settle as its own jitted step —
+    the very executable the chunked grower runs, so the two growers'
+    leaves round alike."""
+    state, node_ids = _fit_forest_jit(codes, codes_cm, g, h,
+                                      lambda_=lambda_, **kw)
+    feature, threshold, is_cat, default_left = state
     return TreeArrays(feature=feature, threshold=threshold, is_cat=is_cat,
-                      default_left=default_left, leaf_value=value_bottom)
+                      default_left=default_left,
+                      leaf_value=_settle_jit(g, h, node_ids, feature,
+                                             lambda_))
 
 
-def _decide_level(hist, level, depth, state, is_cat_field, field_mask,
+def _decide_level(hist, level, state, is_cat_field, field_mask,
                   lambda_, gamma, min_child_weight, find):
     """Step ② for one level: pick splits from the (K, nn, F, NB, 2) level
     histogram and fold them into the tree-table ``state``.  Pure jnp on
     node-sized arrays — shared verbatim by the in-memory (jitted) and
     chunked (host-driven) growers, so both emit identical trees for
     identical histograms."""
-    feature, threshold, is_cat, default_left, value_bottom, value_set = state
+    feature, threshold, is_cat, default_left = state
     K, nn, F, n_bins, _ = hist.shape
     off = nn - 1
-    reps = 2 ** (depth - level)
 
     # find_best_splits is vectorized over nodes: fold the class axis into
     # the node axis (works for the host offload too)
@@ -216,15 +221,11 @@ def _decide_level(hist, level, depth, state, is_cat_field, field_mask,
                 field_mask, lambda_, gamma, min_child_weight)
     best = splits_mod.SplitDecision(*[a.reshape(K, nn) for a in flat])
 
-    resolved = value_set[:, jnp.arange(nn) * reps]              # (K, nn)
+    # a node whose parent did not split is resolved: it holds the
+    # parent's records on the pass-through spine and never splits
+    parent = feature[:, jnp.maximum(nn // 2 - 1, 0) + jnp.arange(nn) // 2]
+    resolved = (parent < 0) if level > 0 else jnp.zeros((K, nn), bool)
     do_split = (best.gain > 0.0) & (~resolved)
-
-    w = splits_mod.leaf_weight(best.node_g, best.node_h, lambda_)
-    newly_leaf = (~do_split) & (~resolved)
-    mask_b = jnp.repeat(newly_leaf, reps, axis=1)               # (K, n_leaf)
-    value_bottom = jnp.where(mask_b & (~value_set),
-                             jnp.repeat(w, reps, axis=1), value_bottom)
-    value_set = value_set | mask_b
 
     feature = jax.lax.dynamic_update_slice(
         feature, jnp.where(do_split, best.feature, -1), (0, off))
@@ -233,20 +234,71 @@ def _decide_level(hist, level, depth, state, is_cat_field, field_mask,
     is_cat = jax.lax.dynamic_update_slice(is_cat, best.is_cat, (0, off))
     default_left = jax.lax.dynamic_update_slice(
         default_left, best.default_left, (0, off))
-    state = (feature, threshold, is_cat, default_left, value_bottom,
-             value_set)
+    state = (feature, threshold, is_cat, default_left)
     return state, best, do_split
 
 
-def _settle_bottom_leaves(g, h, node_ids, value_bottom, value_set, n_leaf,
-                          lambda_):
-    """Leaf weights for every bottom slot not settled by an earlier level."""
-    Gb = jax.vmap(lambda gg, nid: jax.ops.segment_sum(
-        gg.astype(jnp.float32), nid, n_leaf))(g, node_ids)
-    Hb = jax.vmap(lambda hh, nid: jax.ops.segment_sum(
-        hh.astype(jnp.float32), nid, n_leaf))(h, node_ids)
+_LEAF_LANES = 8192     # records summed side by side per leaf-sum step
+
+
+def leaf_sums(stat, node_ids, n_leaf: int):
+    """(K, n) per-record statistic -> (K, n_leaf) per-leaf sums.
+
+    Summed in one explicit order, with selects and adds only: a scan over
+    record blocks accumulates ``_LEAF_LANES`` running sums per leaf, and
+    a halving tree folds them.  A scatter, reduce or contraction would
+    leave the order to XLA, which picks it by how it fuses the program
+    around the op — the in-memory, chunked, fused and sharded growers
+    would then round the same records differently."""
+    K, n = stat.shape
+    lanes = min(_LEAF_LANES, 1 << max(0, (n - 1).bit_length()))
+    pad = -n % lanes
+    stat = jnp.pad(stat.astype(jnp.float32), ((0, 0), (0, pad)))
+    nid = jnp.pad(node_ids, ((0, 0), (0, pad)), constant_values=-1)
+    leaf = jnp.arange(n_leaf, dtype=nid.dtype)[None, :, None]
+
+    def step(acc, xs):
+        s, i = xs                                              # (K, lanes)
+        return acc + jnp.where(i[:, None, :] == leaf, s[:, None, :],
+                               0.0), None
+
+    xs = (stat.reshape(K, -1, lanes).transpose(1, 0, 2),
+          nid.reshape(K, -1, lanes).transpose(1, 0, 2))
+    acc, _ = jax.lax.scan(step, jnp.zeros((K, n_leaf, lanes), jnp.float32),
+                          xs)
+    while acc.shape[-1] > 1:
+        half = acc.shape[-1] // 2
+        acc = acc[..., :half] + acc[..., half:]
+    return acc[..., 0]
+
+
+def settle_leaves(Gb, Hb, feature, lambda_):
+    """(K, n_leaf) bottom-slot G/H sums -> (K, n_leaf) leaf weights.
+
+    A node that stops splitting sends its records left at every later
+    level, so they all land in the leftmost bottom slot of its subtree;
+    every slot of that subtree takes the weight of that anchor slot.
+    Every leaf, early or not, is thus weighed from per-record sums in
+    the one order ``leaf_sums`` fixes — never from a histogram, whose
+    cells round differently per shard count and per strategy."""
+    K, n_leaf = Gb.shape
+    depth = n_leaf.bit_length() - 1
+    j = jnp.arange(n_leaf, dtype=jnp.int32)
+    anchor = jnp.broadcast_to(j, (K, n_leaf))
+    for level in reversed(range(depth)):      # the topmost stopped node wins
+        shift = depth - level
+        stopped = feature[:, (2 ** level - 1) + (j >> shift)] < 0
+        anchor = jnp.where(stopped, (j >> shift) << shift, anchor)
     wb = splits_mod.leaf_weight(Gb, Hb, lambda_)
-    return jnp.where(value_set, value_bottom, wb)
+    return jnp.take_along_axis(wb, anchor, axis=1)
+
+
+@jax.jit
+def _settle_jit(g, h, node_ids, feature, lambda_):
+    """Leaf weights of every bottom slot from the final node ids."""
+    n_leaf = feature.shape[1] + 1
+    return settle_leaves(leaf_sums(g, node_ids, n_leaf),
+                         leaf_sums(h, node_ids, n_leaf), feature, lambda_)
 
 
 # --------------------------------------------------------------------------
@@ -393,14 +445,11 @@ def fit_forest_chunked(chunks, g, h, *, depth: int, n_bins: int,
     K, n = g.shape
     F = int(is_cat_field.shape[0])
     n_int = 2 ** depth - 1
-    n_leaf = 2 ** depth
 
     state = (jnp.full((K, n_int), -1, jnp.int32),      # feature
              jnp.zeros((K, n_int), jnp.int32),         # threshold
              jnp.zeros((K, n_int), jnp.int32),         # is_cat
-             jnp.zeros((K, n_int), jnp.int32),         # default_left
-             jnp.zeros((K, n_leaf), jnp.float32),      # value_bottom
-             jnp.zeros((K, n_leaf), bool))             # value_set
+             jnp.zeros((K, n_int), jnp.int32))         # default_left
     node_ids = np.zeros((K, n), np.int32)
     find = (splits_mod.find_best_splits_host if plan.host_offload_split
             else splits_mod.find_best_splits)
@@ -457,7 +506,7 @@ def fit_forest_chunked(chunks, g, h, *, depth: int, n_bins: int,
             hist = _combine_sibling_hist(prev_hist, hist, is_small)
         prev_hist = hist
         state, best, do_split = _decide_level(
-            hist, level, depth, state, is_cat_field, field_mask, lambda_,
+            hist, level, state, is_cat_field, field_mask, lambda_,
             gamma, min_child_weight, find)
         smaller_is_left = jnp.where(do_split,
                                     2.0 * best.left_h <= best.node_h, False)
@@ -469,12 +518,11 @@ def fit_forest_chunked(chunks, g, h, *, depth: int, n_bins: int,
             codes = jnp.asarray(codes)
         apply_pending(codes, lo, hi, codes.shape[0])
 
-    feature, threshold, is_cat, default_left, value_bottom, value_set = state
-    value_bottom = _settle_bottom_leaves(
-        jnp.asarray(g), jnp.asarray(h), jnp.asarray(node_ids), value_bottom,
-        value_set, n_leaf, lambda_)
+    feature, threshold, is_cat, default_left = state
+    leaf_value = _settle_jit(jnp.asarray(g), jnp.asarray(h),
+                             jnp.asarray(node_ids), feature, lambda_)
     tree = TreeArrays(feature=feature, threshold=threshold, is_cat=is_cat,
-                      default_left=default_left, leaf_value=value_bottom)
+                      default_left=default_left, leaf_value=leaf_value)
     return tree, node_ids
 
 

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.api.plan import ExecutionPlan
 from repro.core import splits as splits_mod
 from repro.core import tree as tree_mod
@@ -117,7 +116,7 @@ def distributed_histogram(mesh: Mesh, codes, g, h, node_ids, *,
         # the paper's end-of-step-① reduction across record partitions
         return jax.lax.psum(hist_l, da)
 
-    fn = shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                        in_specs=(P(da, "model"), P(da), P(da), P(da)),
                        out_specs=P(None, "model"))
     return fn(codes, g, h, node_ids)
@@ -152,7 +151,7 @@ def distributed_split_combine(mesh: Mesh, hist, is_cat_field, field_mask,
 
     # the post-all_gather argmax is replicated across "model" by value, which
     # varying-manual-axes inference cannot prove — disable the static check
-    fn = shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                        in_specs=(P(None, "model"), P("model"), P("model")),
                        out_specs=P(), check_vma=False)
     sel = fn(hist, is_cat_field, field_mask)
@@ -199,7 +198,7 @@ def distributed_partition_bits(mesh: Mesh, node_ids, codes_cm, feat, thr,
         go_left = total != 1          # 0 == pass-through -> left
         return 2 * node_l + (1 - go_left.astype(jnp.int32))
 
-    return shard_map(local, mesh=mesh,
+    return jax.shard_map(local, mesh=mesh,
                          in_specs=(P("model", da), P(da)),
                          out_specs=P(da), check_vma=False)(codes_cm, node_ids)
 
@@ -252,7 +251,7 @@ def distributed_fit_tree(mesh: Mesh, codes, codes_cm, g, h, *, depth: int,
         nn = 2 ** level
         off = nn - 1
         reps = 2 ** (depth - level)
-        hist = shard_map(
+        hist = jax.shard_map(
             functools.partial(local_hist, nn=nn), mesh=mesh,
             in_specs=(P(da, "model"), P(da), P(da), P(da)),
             out_specs=P(None, "model"))(codes, g, h, node_ids)

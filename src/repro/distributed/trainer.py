@@ -50,7 +50,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.api.plan import ExecutionPlan
-from repro.compat import shard_map
 from repro.core import gbdt as gbdt_mod
 from repro.core import losses as losses_mod
 from repro.core import splits as splits_mod
@@ -131,11 +130,9 @@ def _check_data_parallel(mesh: Mesh) -> Tuple[str, ...]:
 def _trainer_kernel_plan(plan: ExecutionPlan) -> ExecutionPlan:
     """The plan the kernels inside the sharded step see: mesh/data-axis
     routing stripped (the step IS the mesh program), chunking dropped from
-    the jit key, partition pinned to the reference kernel (the Pallas
-    partition path is untested inside shard_map), and the step-② host
-    offload disabled (a host round-trip cannot live inside one jit)."""
+    the jit key, and the step-② host offload disabled (a host round-trip
+    cannot live inside one jit)."""
     return plan.replace(mesh=None, data_axes=None, chunk_bytes=None,
-                        partition_strategy="reference",
                         host_offload_split=False).resolved()
 
 
@@ -182,9 +179,7 @@ def _grow_forest_sharded(mesh: Mesh, da: Tuple[str, ...], *, depth: int,
         state = (jnp.full((K, n_int), -1, jnp.int32),      # feature
                  jnp.zeros((K, n_int), jnp.int32),         # threshold
                  jnp.zeros((K, n_int), jnp.int32),         # is_cat
-                 jnp.zeros((K, n_int), jnp.int32),         # default_left
-                 jnp.zeros((K, n_leaf), jnp.float32),      # value_bottom
-                 jnp.zeros((K, n_leaf), bool))             # value_set
+                 jnp.zeros((K, n_int), jnp.int32))         # default_left
         node_ids = jnp.zeros((K, n_loc), jnp.int32)
         part = jax.vmap(functools.partial(ops.partition_level,
                                           missing_bin=missing_bin,
@@ -252,7 +247,7 @@ def _grow_forest_sharded(mesh: Mesh, da: Tuple[str, ...], *, depth: int,
             # step ② — replicated math on the reduced histogram: every
             # shard takes the same decisions and grows the same tree
             state, best, do_split = tree_mod._decide_level(
-                hist, level, depth, state, is_cat_field, field_mask,
+                hist, level, state, is_cat_field, field_mask,
                 lambda_, gamma, min_child_weight,
                 splits_mod.find_best_splits)
             # step ③ — route the local records only
@@ -268,22 +263,18 @@ def _grow_forest_sharded(mesh: Mesh, da: Tuple[str, ...], *, depth: int,
                                            (K, nn)), -1),
                 best.threshold, best.is_cat, best.default_left)
 
-        feature, threshold, is_cat, default_left, value_bottom, value_set \
-            = state
-        # step ④ — bottom-leaf weights from psum'd per-shard G/H sums
-        Gb = jax.lax.psum(jax.vmap(lambda gg, nid: jax.ops.segment_sum(
-            gg.astype(jnp.float32), nid, n_leaf))(g_l, node_ids), da)
-        Hb = jax.lax.psum(jax.vmap(lambda hh, nid: jax.ops.segment_sum(
-            hh.astype(jnp.float32), nid, n_leaf))(h_l, node_ids), da)
-        wb = splits_mod.leaf_weight(Gb, Hb, lambda_)
-        value_bottom = jnp.where(value_set, value_bottom, wb)
-        return (feature, threshold, is_cat, default_left, value_bottom,
+        feature, threshold, is_cat, default_left = state
+        # step ④ — leaf weights from psum'd per-shard G/H sums
+        Gb = jax.lax.psum(tree_mod.leaf_sums(g_l, node_ids, n_leaf), da)
+        Hb = jax.lax.psum(tree_mod.leaf_sums(h_l, node_ids, n_leaf), da)
+        leaf_value = tree_mod.settle_leaves(Gb, Hb, feature, lambda_)
+        return (feature, threshold, is_cat, default_left, leaf_value,
                 node_ids)
 
     # tree tables are replicated by VALUE (identical psum'd inputs on every
     # shard), which varying-manual-axes inference cannot prove — turn the
     # static check off, as the other shard_map paths in sharding.py do
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(da), P(None, da), P(None, da), P(None, da), P(), P()),
         out_specs=(P(), P(), P(), P(), P(), P(None, da)),
@@ -337,8 +328,7 @@ def _distributed_round_step(config: GBDTConfig, plan: ExecutionPlan,
         h2 = jnp.pad(h2, ((0, 0), (0, n_pad - n)))
         forest, node_ids = grow(codes, codes_cm, g2, h2, is_cat_field,
                                 field_mask)
-        forest = forest._replace(
-            leaf_value=forest.leaf_value * config.learning_rate)
+        forest = gbdt_mod.shrink(forest, config.learning_rate)
         # step ⑤ for free: final node ids ARE bottom-leaf slots
         delta = jax.vmap(lambda v, i: v[i])(forest.leaf_value,
                                             node_ids)[:, :n]   # (Kb, n)
@@ -618,6 +608,7 @@ def train_distributed(config: GBDTConfig, data: BinnedDataset, y, *,
 
     def _mkstats(**extra):
         return {"n_rows": n, "distributed": True,
+                "kernel_plan": kernel_plan.describe(),
                 "n_shards": n_data_shards(mesh), "restarts": restarts,
                 "remesh_events": events, "hist_slices": hist_slices,
                 **rstats, **extra}

@@ -18,9 +18,22 @@ group-by-field mapping (paper §III-A/B):
 
   * Group-by-field becomes a *BlockSpec* statement: the grid tiles the field
     dimension so one grid cell owns ``FBLK`` whole fields, and the VMEM
-    accumulator tile ``(FBLK, NB, NN*2)`` keeps *all bins of a field
+    accumulator tile ``(FBLK, NN*2, NB)`` keeps *all bins of a field
     together* — one small matmul per field per record-block, never a bin tile
     shared between fields.
+
+  * Records ride the lane axis.  The codes are streamed field-major
+    (``(F, n)``, the paper's column-major copy), so a field's one-hot is a
+    sublane broadcast of one code row against a bin iota, and the per-field
+    contraction is ``stats (S, R) · one_hot (NB, R)ᵀ`` — both operands
+    lane-dense, no reshapes, no gathers.
+
+  * Exact arithmetic on the MXU.  The one-hot is exact in bfloat16; the
+    float32 stats are split into three bfloat16 parts (hi + mid + lo
+    reproduce every normal float32 exactly), stacked on the sublane axis
+    and contracted in ONE matmul with float32 accumulation.  The result
+    then differs from a float32 scatter only by summation order — a single
+    bfloat16 pass would round every (g, h) to 8 mantissa bits.
 
   * The record stream is the grid's fast axis; Pallas double-buffers the
     HBM→VMEM block DMA exactly like the paper's double-buffered record fetch
@@ -49,76 +62,102 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from repro.core.binning import PackedCodes
+from repro.kernels.tiling import LANES, STAT_ROWS, SUBLANES, round_up
 
 
 def _iota(shape, dim):
     return lax.broadcasted_iota(jnp.int32, shape, dim)
 
 
-def _stats_node(node_ref, g_ref, h_ref, n_nodes: int):
-    """(RBLK, K*NN*2) outer-product spread of (g, h) over node slots.
-
-    The class axis K (multi-class boosting: one tree per class per round,
-    each with its own node partition) widens the stats operand of the
-    one-hot contraction — the record/code stream is read ONCE and a single
-    K*NN*2-wide matmul accumulates every class's (g, h), preserving the
-    paper's field→SRAM bandwidth mapping at K× arithmetic intensity."""
-    rblk, K = node_ref.shape
-    node = node_ref[...].astype(jnp.int32)                  # (RBLK, K)
-    oh_node = (node[:, :, None] == _iota((rblk, K, n_nodes), 2)
-               ).astype(jnp.float32)                        # (RBLK, K, NN)
-    stats = jnp.stack(
-        [g_ref[...].astype(jnp.float32), h_ref[...].astype(jnp.float32)],
-        axis=2)                                             # (RBLK, K, 2)
-    sn = oh_node[:, :, :, None] * stats[:, :, None, :]      # (RBLK, K, NN, 2)
-    return sn.reshape(rblk, K * n_nodes * 2)
+def split_bf16x3(x):
+    """float32 -> (hi, mid, lo) bfloat16 parts with hi + mid + lo == x for
+    every normal float32 (each part takes the next 8 mantissa bits of the
+    remainder, and each remainder is exact in float32)."""
+    hi = x.astype(jnp.bfloat16)
+    r = x - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    lo = (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
 
 
-def _hist_kernel_grouped(codes_ref, node_ref, g_ref, h_ref, hist_ref, *,
-                         n_bins: int, n_nodes: int, nibble_packed: bool):
-    """Group-by-field: every field owns its own (RBLK, NB) one-hot tile
-    and its own bin rows of the accumulator, contracted against the
-    shared stats operand in ONE field-batched dot — not a Python-unrolled
-    per-field matmul chain, which serialized the kernel into ``FBLK``
-    dependent MXU issues per block.
+def _stats_t(node_ref, g_ref, h_ref, n_nodes: int, n_rows: int):
+    """(S, RBLK) float32 spread of (g, h) over the node slots, S >= K*NN*2.
 
-    ``nibble_packed``: the code block arrives as packed bytes
-    (RBLK, FBLK/2) and is unpacked to nibbles here, in VMEM — the block
-    DMA from HBM moves half the bytes."""
+    Row ``s = (k*NN + nn)*2 + c`` holds class k's statistic c (0 = g,
+    1 = h) for the records sitting in node nn, zero elsewhere.  The class
+    axis K (multi-class boosting: one tree per class per round, each with
+    its own node partition) widens the stats operand — the code stream is
+    read ONCE and a single matmul accumulates every class's (g, h).
+    Records whose node id lies outside [0, NN) contribute nothing."""
+    K, rblk = node_ref.shape
+    s = _iota((n_rows, rblk), 0)
+    slot, is_h = s >> 1, (s & 1) == 1
+    out = jnp.zeros((n_rows, rblk), jnp.float32)
+    for k in range(K):                                     # static, K small
+        node = node_ref[k:k + 1, :]                        # (1, RBLK)
+        valid = (node >= 0) & (node < n_nodes)
+        stat = jnp.where(is_h, h_ref[k:k + 1, :], g_ref[k:k + 1, :])
+        hit = (slot == node + k * n_nodes) & valid
+        out = jnp.where(hit, stat, out)
+    return out
+
+
+def _hist_kernel(codes_ref, node_ref, g_ref, h_ref, hist_ref, *,
+                 n_bins: int, n_nodes: int, nibble_packed: bool,
+                 packed: bool):
+    """One (field block, record block) grid cell.
+
+    ``codes_ref``: (FBLK, RBLK) uint8 codes, field-major — or (FBLK/2,
+    RBLK) nibble-packed bytes, unpacked here in VMEM (the block DMA from
+    HBM moves half the bytes).  ``hist_ref``: the (FBLK, S, NB)
+    accumulator, revisited along the record axis.
+
+    Group-by-field (``packed=False``) contracts each field's own
+    (NB, RBLK) one-hot.  The naive-packing ablation (``packed=True``)
+    concatenates the block's one-hots into one FBLK*NB-wide tile and
+    issues a single matmul — identical MACs, an FBLK× larger transient."""
     @pl.when(pl.program_id(1) == 0)
     def _init():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    raw = codes_ref[...]
-    if nibble_packed:
-        raw = jnp.stack([raw & 0xF, raw >> 4],
-                        axis=-1).reshape(raw.shape[0], -1)  # (RBLK, FBLK)
-    rblk, fblk = raw.shape
-    codes = raw.astype(jnp.int32)                           # (RBLK, FBLK)
-    sn = _stats_node(node_ref, g_ref, h_ref, n_nodes)       # (RBLK, NN*2)
-    oh_bin = (codes[:, :, None] == _iota((rblk, fblk, n_bins), 2)
-              ).astype(jnp.float32)                         # (RBLK, FBLK, NB)
-    # contract the record axis once for all FBLK fields: (FBLK, NB, NN*2)
-    contrib = lax.dot_general(oh_bin, sn, (((0,), (0,)), ((), ())),
+    fblk, n_rows, _ = hist_ref.shape
+    rblk = node_ref.shape[1]
+    lhs = jnp.concatenate(
+        split_bf16x3(_stats_t(node_ref, g_ref, h_ref, n_nodes, n_rows)),
+        axis=0)                                            # (3S, RBLK) bf16
+    raw = codes_ref[...].astype(jnp.int32)
+    bins = _iota((n_bins, rblk), 0)
+
+    def one_hot(f):
+        if nibble_packed:
+            row = (raw[f // 2:f // 2 + 1, :] >> (4 * (f % 2))) & 0xF
+        else:
+            row = raw[f:f + 1, :]
+        return jnp.where(row == bins, 1.0, 0.0).astype(jnp.bfloat16)
+
+    def contract(oh):                                      # -> (S, width)
+        acc = lax.dot_general(lhs, oh, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
-    hist_ref[...] += contrib
+        return (acc[:n_rows] + acc[n_rows:2 * n_rows]) + acc[2 * n_rows:]
+
+    if packed:
+        flat = contract(jnp.concatenate([one_hot(f) for f in range(fblk)],
+                                        axis=0))
+        for f in range(fblk):
+            hist_ref[f] += flat[:, f * n_bins:(f + 1) * n_bins]
+    else:
+        for f in range(fblk):
+            hist_ref[f] += contract(one_hot(f))
 
 
-def _hist_kernel_packed(codes_ref, node_ref, g_ref, h_ref, hist_ref, *,
-                        n_bins: int, n_nodes: int):
-    """Naive packing baseline: single FBLK*NB-wide one-hot tile (Fig 9)."""
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
-
-    rblk, fblk = codes_ref.shape
-    codes = codes_ref[...].astype(jnp.int32)
-    sn = _stats_node(node_ref, g_ref, h_ref, n_nodes)
-    oh = (codes[:, :, None] == _iota((rblk, fblk, n_bins), 2)
-          ).astype(jnp.float32).reshape(rblk, fblk * n_bins)
-    flat = lax.dot_general(oh, sn, (((0,), (0,)), ((), ())),
-                           preferred_element_type=jnp.float32)
-    hist_ref[...] += flat.reshape(fblk, n_bins, sn.shape[1])
+def _field_block(F: int, fields_per_block: int, nibble: bool) -> int:
+    """Fields per grid cell: all of them when they fit one block, else a
+    multiple of the sublane count (of packed bytes, for nibble codes)."""
+    quantum = 2 * SUBLANES if nibble else SUBLANES
+    fblk = round_up(max(fields_per_block, 1), quantum)
+    if fblk >= F:
+        return F + (F % 2 if nibble else 0)
+    return fblk
 
 
 @functools.partial(
@@ -126,8 +165,8 @@ def _hist_kernel_packed(codes_ref, node_ref, g_ref, h_ref, hist_ref, *,
     static_argnames=("n_nodes", "n_bins", "records_per_block",
                      "fields_per_block", "packed", "interpret"))
 def histogram_pallas(codes, g, h, node_ids, *, n_nodes: int, n_bins: int,
-                     records_per_block: int = 512, fields_per_block: int = 8,
-                     packed: bool = False, interpret: bool = True):
+                     records_per_block: int, fields_per_block: int,
+                     packed: bool, interpret: bool):
     """Histogram binning via the one-hot MXU kernel.
 
     codes: (n, F) uint8, or a :class:`PackedCodes` carrying the same
@@ -140,7 +179,7 @@ def histogram_pallas(codes, g, h, node_ids, *, n_nodes: int, n_bins: int,
 
     Class-batched form: g, h, node_ids may carry a leading class axis
     (K, n) — one launch then reads codes once and accumulates all K
-    classes' statistics through a K*NN*2-wide stats operand, returning
+    classes' statistics through a K*NN*2-row stats operand, returning
     (K, n_nodes, F, n_bins, 2).
     """
     nibble = isinstance(codes, PackedCodes)
@@ -149,58 +188,43 @@ def histogram_pallas(codes, g, h, node_ids, *, n_nodes: int, n_bins: int,
         codes, nibble = codes.unpack(), False
 
     batched = g.ndim == 2
-    K = g.shape[0] if batched else 1
-    # kernel-facing layout: records major, classes minor — (n, K) columns
-    g2 = g.T if batched else g[:, None]
-    h2 = h.T if batched else h[:, None]
-    node2 = node_ids.T if batched else node_ids[:, None]
+    g2 = (g if batched else g[None]).astype(jnp.float32)       # (K, n)
+    h2 = (h if batched else h[None]).astype(jnp.float32)
+    node2 = (node_ids if batched else node_ids[None]).astype(jnp.int32)
+    K = g2.shape[0]
 
     n, F = codes.shape
-    rblk = min(records_per_block, max(8, n))
-    fblk = min(fields_per_block, F)
-    if nibble and fblk % 2:
-        fblk += 1          # nibble blocks cover whole packed bytes
-    n_pad = -n % rblk
-    f_pad = -F % fblk
-    g2 = jnp.pad(g2, ((0, n_pad), (0, 0)))
-    h2 = jnp.pad(h2, ((0, n_pad), (0, 0)))
-    node2 = jnp.pad(node2, ((0, n_pad), (0, 0)))
-    Fp = F + f_pad
-    np_ = n + n_pad
-    grid = (Fp // fblk, np_ // rblk)  # fields outer, record stream inner
+    rblk = min(round_up(records_per_block, LANES), round_up(n, LANES))
+    fblk = _field_block(F, fields_per_block, nibble)
+    np_ = round_up(n, rblk)
+    Fp = round_up(F, fblk)
+    n_rows = round_up(K * n_nodes * 2, STAT_ROWS)
+    pad_r = ((0, 0), (0, np_ - n))
+    g2, h2, node2 = (jnp.pad(a, pad_r) for a in (g2, h2, node2))
 
+    # field-major code stream; pad fields only feed the sliced-off hist
+    # rows >= F, pad records carry zero stats
     if nibble:
-        # pad the packed BYTES; pad fields unpack to code 0 and only feed
-        # the sliced-off hist rows >= F, pad records carry zero stats
-        data = codes.data
-        code_op = jnp.pad(data, ((0, n_pad), (0, Fp // 2 - data.shape[1])))
-        code_spec = pl.BlockSpec((rblk, fblk // 2), lambda fi, ri: (ri, fi))
+        data = codes.data.T                                    # (ceil(F/2), n)
+        code_op = jnp.pad(data, ((0, Fp // 2 - data.shape[0]),
+                                 (0, np_ - n)))
+        code_spec = pl.BlockSpec((fblk // 2, rblk), lambda fi, ri: (fi, ri))
     else:
-        code_op = jnp.pad(codes, ((0, n_pad), (0, f_pad)))
-        code_spec = pl.BlockSpec((rblk, fblk), lambda fi, ri: (ri, fi))
+        code_op = jnp.pad(codes.T, ((0, Fp - F), (0, np_ - n)))
+        code_spec = pl.BlockSpec((fblk, rblk), lambda fi, ri: (fi, ri))
 
-    if packed:
-        kernel = functools.partial(_hist_kernel_packed, n_bins=n_bins,
-                                   n_nodes=n_nodes)
-    else:
-        kernel = functools.partial(_hist_kernel_grouped, n_bins=n_bins,
-                                   n_nodes=n_nodes, nibble_packed=nibble)
+    stat_spec = pl.BlockSpec((K, rblk), lambda fi, ri: (0, ri))
     out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            code_spec,
-            pl.BlockSpec((rblk, K), lambda fi, ri: (ri, 0)),
-            pl.BlockSpec((rblk, K), lambda fi, ri: (ri, 0)),
-            pl.BlockSpec((rblk, K), lambda fi, ri: (ri, 0)),
-        ],
-        out_specs=pl.BlockSpec((fblk, n_bins, K * n_nodes * 2),
+        functools.partial(_hist_kernel, n_bins=n_bins, n_nodes=n_nodes,
+                          nibble_packed=nibble, packed=packed),
+        grid=(Fp // fblk, np_ // rblk),   # fields outer, record stream inner
+        in_specs=[code_spec, stat_spec, stat_spec, stat_spec],
+        out_specs=pl.BlockSpec((fblk, n_rows, n_bins),
                                lambda fi, ri: (fi, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((Fp, n_bins, K * n_nodes * 2),
-                                       jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((Fp, n_rows, n_bins), jnp.float32),
         interpret=interpret,
     )(code_op, node2, g2, h2)
 
-    hist = out[:F].reshape(F, n_bins, K, n_nodes, 2)
-    hist = hist.transpose(2, 3, 0, 1, 4)            # (K, NN, F, NB, 2)
+    hist = out[:F, :K * n_nodes * 2].reshape(F, K, n_nodes, 2, n_bins)
+    hist = hist.transpose(1, 2, 0, 4, 3)            # (K, NN, F, NB, 2)
     return hist if batched else hist[0]

@@ -16,10 +16,12 @@ traffic*:
     * ``pallas_packed``    — the naive-packing ablation kernel
 
   traversal / inference (step ⑤, §III-D) and partition (step ③):
-    * ``reference`` (gather walk)  vs  ``pallas`` (one-hot walk)
+    * ``reference`` (gather walk)  vs  ``pallas`` (compare walk)
 
-On non-TPU backends the Pallas kernels run in interpret mode (Python
-execution of the kernel body) — numerically identical, used for validation.
+The resolved plan decides interpret mode: off on a TPU, where the kernels
+compile through Mosaic, and on elsewhere (Python execution of the kernel
+body — numerically identical, used for validation).  A kernel that fails
+raises: a dispatch runs the strategy its plan names, never another.
 
 Calling convention: ``build_histogram(..., plan=plan)`` with a resolved
 plan.  The PR-1 loose ``strategy=`` / ``interpret=`` kwargs (and their
@@ -31,8 +33,6 @@ call.
 from __future__ import annotations
 
 import functools
-import warnings
-from collections import Counter
 from typing import Optional
 
 import jax
@@ -47,94 +47,15 @@ from repro.kernels import partition as _part_k
 from repro.kernels import traversal as _trav_k
 from repro.kernels import ref as _ref
 from repro.kernels.ref import TreeArrays
-from repro.resilience import metrics as _metrics
+
+# float32 operands of a jnp contraction would take one bfloat16 MXU pass
+# on a TPU at default precision; the one-hot contractions here carry exact
+# (g, h) and leaf values, so they ask for full float32
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 __all__ = ["HIST_STRATEGIES", "onehot_matmul", "pack_codes", "unpack_codes",
            "build_histogram", "accumulate_histogram", "partition_level",
-           "traverse_tree", "predict_ensemble", "pallas_available",
-           "degradation_stats", "reset_degradation_stats"]
-
-
-# --------------------------------------------------------------------------
-# graceful kernel degradation: a broken Pallas lowering degrades
-# throughput, never correctness
-# --------------------------------------------------------------------------
-_DEGRADATIONS: Counter = Counter()
-_DEGRADE_WARNED: set = set()
-
-
-def degradation_stats() -> dict:
-    """``{"step:strategy->fallback": count}`` of every Pallas demotion
-    this process took (also mirrored into the process-wide
-    ``resilience.metrics`` ``"degradations"`` counter)."""
-    return dict(_DEGRADATIONS)
-
-
-def reset_degradation_stats() -> dict:
-    """Zero the per-step demotion counters (the one-time warning latch
-    stays latched); returns the pre-reset values."""
-    old = dict(_DEGRADATIONS)
-    _DEGRADATIONS.clear()
-    return old
-
-
-def _degrade(step: str, strategy: str, fallback: str,
-             exc: Exception) -> None:
-    """Record one kernel demotion: count it, and warn ONCE per
-    (step, strategy) so a chunked fit does not emit a warning per
-    dispatch."""
-    _DEGRADATIONS[f"{step}:{strategy}->{fallback}"] += 1
-    _metrics.record("degradations")
-    key = (step, strategy)
-    if key not in _DEGRADE_WARNED:
-        _DEGRADE_WARNED.add(key)
-        warnings.warn(
-            f"Pallas {step} kernel (strategy {strategy!r}) failed "
-            f"({type(exc).__name__}: {exc}); demoting to the "
-            f"{fallback!r} jnp path for this call — throughput "
-            "degrades, correctness does not",
-            RuntimeWarning, stacklevel=4)
-
-
-@functools.lru_cache(maxsize=None)
-def pallas_available(step: str, interpret: bool = True) -> bool:
-    """Probe whether the ``step`` Pallas kernel actually launches on
-    this backend (tiny input, one compile, cached per process).
-
-    ``ExecutionPlan.resolved()`` consults this before electing a Pallas
-    strategy so a backend with a broken lowering resolves straight to
-    the jnp twin instead of demoting on the first real dispatch.
-    ``step``: ``"histogram"`` | ``"partition"`` | ``"traversal"``.
-    """
-    if step not in ("histogram", "partition", "traversal"):
-        # outside the probe's try block: a typo'd step name must raise,
-        # not read as "kernel unavailable"
-        raise ValueError(f"unknown probe step {step!r}")
-    try:
-        if step == "histogram":
-            out = _hist_k.histogram_pallas(
-                jnp.zeros((16, 2), jnp.uint8), jnp.ones((16,), jnp.float32),
-                jnp.ones((16,), jnp.float32), jnp.zeros((16,), jnp.int32),
-                n_nodes=1, n_bins=4, records_per_block=16,
-                fields_per_block=2, packed=False, interpret=interpret)
-        elif step == "partition":
-            out = _part_k.partition_pallas(
-                jnp.zeros((8,), jnp.int32), jnp.zeros((8, 1), jnp.uint8),
-                jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-                jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-                missing_bin=3, interpret=interpret)
-        else:
-            tree = TreeArrays(feature=jnp.zeros((1,), jnp.int32),
-                              threshold=jnp.zeros((1,), jnp.int32),
-                              is_cat=jnp.zeros((1,), jnp.int32),
-                              default_left=jnp.zeros((1,), jnp.int32),
-                              leaf_value=jnp.zeros((2,), jnp.float32))
-            out = _trav_k.traverse_pallas(tree, jnp.zeros((8, 1), jnp.uint8),
-                                          missing_bin=3, interpret=interpret)
-        jax.block_until_ready(out)
-        return True
-    except Exception:  # noqa: BLE001 — any launch/lowering failure
-        return False
+           "traverse_tree", "predict_ensemble"]
 
 
 # --------------------------------------------------------------------------
@@ -172,6 +93,7 @@ def onehot_matmul(idx: jax.Array, values: jax.Array, width: int) -> jax.Array:
     oh = jax.nn.one_hot(idx, width, dtype=values.dtype)        # (n, width)
     flat = values.reshape(values.shape[0], -1)
     out = jax.lax.dot_general(oh, flat, (((0,), (0,)), ((), ())),
+                              precision=_HIGHEST,
                               preferred_element_type=jnp.float32)
     return out.reshape((width,) + values.shape[1:])
 
@@ -238,7 +160,7 @@ def _hist_onehot(codes, g, h, node_ids, n_nodes, n_bins, chunk=2048, fblk=8):
         sn = (oh_node[:, :, None] * s[:, None, :]).reshape(chunk, n_nodes * 2)
         oh_bin = jax.nn.one_hot(c.astype(jnp.int32), n_bins,
                                 dtype=jnp.float32)             # (chunk, Fp, NB)
-        contrib = jnp.einsum("nfb,ns->fbs", oh_bin, sn,
+        contrib = jnp.einsum("nfb,ns->fbs", oh_bin, sn, precision=_HIGHEST,
                              preferred_element_type=jnp.float32)
         return acc + contrib, None
 
@@ -291,20 +213,12 @@ def build_histogram(codes, g, h, node_ids, *, n_nodes: int, n_bins: int,
                                                 n_bins)
         return per_class(fn)(codes, g, h, node_ids)
     if strategy in ("pallas_grouped", "pallas_packed"):
-        try:
-            return _hist_k.histogram_pallas(
-                codes, g, h, node_ids, n_nodes=n_nodes, n_bins=n_bins,
-                records_per_block=plan.records_per_block,
-                fields_per_block=plan.fields_per_block,
-                packed=(strategy == "pallas_packed"),
-                interpret=plan.interpret)
-        except Exception as exc:  # noqa: BLE001 — demote, never corrupt
-            _degrade("histogram", strategy, "scatter", exc)
-            if isinstance(codes, PackedCodes):
-                codes = codes.unpack()
-            fn = lambda c, gg, hh, nn: _hist_scatter(c, gg, hh, nn,
-                                                     n_nodes, n_bins)
-            return per_class(fn)(codes, g, h, node_ids)
+        return _hist_k.histogram_pallas(
+            codes, g, h, node_ids, n_nodes=n_nodes, n_bins=n_bins,
+            records_per_block=plan.records_per_block,
+            fields_per_block=plan.fields_per_block,
+            packed=(strategy == "pallas_packed"),
+            interpret=plan.interpret)
     raise ValueError(f"unknown histogram strategy {strategy!r}; "
                      f"choose from {HIST_STRATEGIES}")
 
@@ -323,6 +237,16 @@ def _accumulate_jit(n_nodes: int, n_bins: int, plan: ExecutionPlan):
     donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
 
     def impl(hist, codes, g, h, node_ids):
+        if plan.hist_strategy == "scatter":
+            # scatter into the running accumulator: the chunked sum then
+            # rounds exactly like one scatter over every chunk's records
+            if isinstance(codes, PackedCodes):
+                codes = codes.unpack()
+            acc = lambda a, gg, hh, nn: _ref.histogram_ref(
+                codes, gg, hh, nn, n_nodes, n_bins, init=a)
+            if g.ndim == 2:
+                return jax.vmap(acc)(hist, g, h, node_ids)
+            return acc(hist, g, h, node_ids)
         return hist + build_histogram(codes, g, h, node_ids, n_nodes=n_nodes,
                                       n_bins=n_bins, plan=plan)
 
@@ -361,16 +285,10 @@ def partition_level(node_ids, codes_lvl, split_feature, split_threshold,
         return _ref.partition_ref(node_ids, codes_lvl, split_feature,
                                   split_threshold, split_is_cat,
                                   split_default_left, missing_bin)
-    try:
-        return _part_k.partition_pallas(
-            node_ids, codes_lvl, split_feature, split_threshold,
-            split_is_cat, split_default_left, missing_bin=missing_bin,
-            interpret=plan.interpret)
-    except Exception as exc:  # noqa: BLE001 — demote, never corrupt
-        _degrade("partition", plan.partition_strategy, "reference", exc)
-        return _ref.partition_ref(node_ids, codes_lvl, split_feature,
-                                  split_threshold, split_is_cat,
-                                  split_default_left, missing_bin)
+    return _part_k.partition_pallas(
+        node_ids, codes_lvl, split_feature, split_threshold,
+        split_is_cat, split_default_left, missing_bin=missing_bin,
+        interpret=plan.interpret)
 
 
 # --------------------------------------------------------------------------
@@ -384,12 +302,8 @@ def traverse_tree(tree: TreeArrays, codes, *, missing_bin: int,
     # "scan" only changes multi-tree inference; a single walk is a walk
     if plan.traversal_strategy in ("reference", "scan"):
         return _ref.traverse_ref(tree, codes, missing_bin)
-    try:
-        return _trav_k.traverse_pallas(tree, codes, missing_bin=missing_bin,
-                                       interpret=plan.interpret)
-    except Exception as exc:  # noqa: BLE001 — demote, never corrupt
-        _degrade("traversal", plan.traversal_strategy, "reference", exc)
-        return _ref.traverse_ref(tree, codes, missing_bin)
+    return _trav_k.traverse_pallas(tree, codes, missing_bin=missing_bin,
+                                   interpret=plan.interpret)
 
 
 _PREDICT_ROWS_PER_CHUNK = 1024   # (chunk, T) walk state stays cache-sized
@@ -443,6 +357,7 @@ def _predict_batched_jit(trees, codes, missing_bin, n_classes):
         if cls_oh is None:
             return jnp.sum(vals, axis=1)
         return jax.lax.dot_general(vals, cls_oh, (((1,), (0,)), ((), ())),
+                                   precision=_HIGHEST,
                                    preferred_element_type=jnp.float32)
 
     chunk = min(_PREDICT_ROWS_PER_CHUNK, max(1, n))
@@ -471,11 +386,7 @@ def predict_ensemble(trees: TreeArrays, codes, *, missing_bin: int,
                                          n_classes=n_classes)
     if plan.traversal_strategy == "reference":
         return _predict_batched_jit(trees, codes, missing_bin, n_classes)
-    try:
-        return _trav_k.predict_ensemble_pallas(
-            trees, codes, missing_bin=missing_bin, depth=depth,
-            interpret=plan.interpret, n_classes=n_classes,
-            trees_per_block=plan.trees_per_block)
-    except Exception as exc:  # noqa: BLE001 — demote, never corrupt
-        _degrade("predict", plan.traversal_strategy, "reference", exc)
-        return _predict_batched_jit(trees, codes, missing_bin, n_classes)
+    return _trav_k.predict_ensemble_pallas(
+        trees, codes, missing_bin=missing_bin, depth=depth,
+        interpret=plan.interpret, n_classes=n_classes,
+        trees_per_block=plan.trees_per_block)

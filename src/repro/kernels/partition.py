@@ -7,7 +7,7 @@ pointer to the predicate-true or predicate-false stream.
 
 Our level-wise grower evaluates *all* of a level's predicates in one pass:
 each record carries its level-local node id, and the level's split table
-(one predicate per node, ≤ 2**level entries — tiny, VMEM-replicated like the
+(one predicate per node, ≤ 2**level entries — tiny, held in SMEM like the
 paper's broadcast) decides left/right.  The routed result is the record's
 child node id; the fixed-shape design replaces the paper's pointer streams
 with an in-place id update (stream compaction is only needed by the
@@ -16,6 +16,10 @@ leaf-wise grower and is done with a sort there).
 The field columns consumed here are gathered from the column-major copy —
 only the ≤ NN fields named by the level's predicates travel HBM→VMEM, which
 is the redundant-representation bandwidth saving of §III (steps ③/⑤).
+Records ride the lane axis: the kernel walks the level's nodes, reads each
+node's predicate as SMEM scalars and its field as one code row, and routes
+the records sitting in that node — integer compares only, so the result is
+bit-exact on every backend.
 """
 from __future__ import annotations
 
@@ -25,68 +29,61 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.tiling import LANES, round_up
 
 
-def _iota(shape, dim):
-    return lax.broadcasted_iota(jnp.int32, shape, dim)
+def _partition_kernel(table_ref, node_ref, codes_ref, out_ref, *,
+                      missing_bin: int, n_nodes: int):
+    node = node_ref[...]                                      # (1, RBLK)
 
+    def route(j, child):
+        # predicate words are SMEM scalars; decisions stay int32 0/1 (the
+        # chip has no vector form of a scalar-predicated boolean select)
+        f = table_ref[0, j]
+        thr = table_ref[1, j]
+        is_cat = table_ref[2, j]
+        code = codes_ref[pl.ds(jnp.maximum(f, 0), 1), :]      # (1, RBLK)
+        left = (is_cat * (code == thr).astype(jnp.int32)
+                + (1 - is_cat) * (code <= thr).astype(jnp.int32))
+        left = jnp.where(code == missing_bin, table_ref[3, j], left)
+        left = jnp.maximum(left, (f < 0).astype(jnp.int32))   # pass-through
+        return jnp.where(node == j, 2 * j + 1 - left, child)
 
-def _iota_f(shape, dim):
-    return lax.broadcasted_iota(jnp.float32, shape, dim)
-
-
-def _partition_kernel(node_ref, codes_ref, table_ref, out_ref, *,
-                      missing_bin: int):
-    rblk = codes_ref.shape[0]
-    n_nodes, _ = table_ref.shape
-    n_cols = codes_ref.shape[1]
-    node = node_ref[...].astype(jnp.int32)                    # (RBLK, 1)
-    codes = codes_ref[...].astype(jnp.float32)                # (RBLK, C)
-    table = table_ref[...]                                    # (NN, 4) f32
-    oh_node = (node == _iota((rblk, n_nodes), 1)).astype(jnp.float32)
-    params = lax.dot_general(oh_node, table, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    f = params[:, 0:1]
-    thr = params[:, 1:2]
-    cat = params[:, 2:3]
-    dl = params[:, 3:4]
-    oh_f = (f == _iota_f((rblk, n_cols), 1)).astype(jnp.float32)
-    code = jnp.sum(oh_f * codes, axis=1, keepdims=True)
-    go_left = jnp.where(cat == 1.0, code == thr, code <= thr)
-    go_left = jnp.where(code == float(missing_bin), dl == 1.0, go_left)
-    go_left = jnp.where(f < 0.0, True, go_left)
-    out_ref[...] = 2 * node + (1 - go_left.astype(jnp.int32))
+    out_ref[...] = lax.fori_loop(0, n_nodes, route, 2 * node)
 
 
 @functools.partial(jax.jit, static_argnames=("missing_bin",
                                              "records_per_block", "interpret"))
 def partition_pallas(node_ids, codes_lvl, split_feature, split_threshold,
                      split_is_cat, split_default_left, *, missing_bin: int,
-                     records_per_block: int = 1024, interpret: bool = True):
+                     records_per_block: int = 1024, interpret: bool):
     """Route records to children.  Level-local ids: out in [0, 2*NN).
 
     node_ids (n,) int32; codes_lvl (n, C) uint8 compact per-level columns;
     split_* (NN,) with split_feature indexing [0, C) or -1 (pass-through).
     """
     n, n_cols = codes_lvl.shape
-    rblk = min(records_per_block, max(8, n))
-    n_pad = -n % rblk
-    codes_lvl = jnp.pad(codes_lvl, ((0, n_pad), (0, 0)))
-    node_ids_p = jnp.pad(node_ids, (0, n_pad))
-    np_ = codes_lvl.shape[0]
+    rblk = min(round_up(records_per_block, LANES), round_up(n, LANES))
+    np_ = round_up(n, rblk)
+    # column-major int32 rows: the kernel reads one code row per node
+    codes_t = jnp.pad(codes_lvl.T.astype(jnp.int32), ((0, 0), (0, np_ - n)))
+    node_p = jnp.pad(node_ids.astype(jnp.int32), (0, np_ - n))[None]
     n_nodes = split_feature.shape[0]
     table = jnp.stack([split_feature, split_threshold, split_is_cat,
-                       split_default_left], axis=1).astype(jnp.float32)
+                       split_default_left]).astype(jnp.int32)   # (4, NN)
     out = pl.pallas_call(
-        functools.partial(_partition_kernel, missing_bin=missing_bin),
+        functools.partial(_partition_kernel, missing_bin=missing_bin,
+                          n_nodes=n_nodes),
         grid=(np_ // rblk,),
         in_specs=[
-            pl.BlockSpec((rblk, 1), lambda ri: (ri, 0)),
-            pl.BlockSpec((rblk, n_cols), lambda ri: (ri, 0)),
-            pl.BlockSpec((n_nodes, 4), lambda ri: (0, 0)),    # replicated
+            pl.BlockSpec(memory_space=pltpu.SMEM),            # replicated
+            pl.BlockSpec((1, rblk), lambda ri: (0, ri)),
+            pl.BlockSpec((n_cols, rblk), lambda ri: (0, ri)),
         ],
-        out_specs=pl.BlockSpec((rblk, 1), lambda ri: (ri, 0)),
-        out_shape=jax.ShapeDtypeStruct((np_, 1), jnp.int32),
+        out_specs=pl.BlockSpec((1, rblk), lambda ri: (0, ri)),
+        out_shape=jax.ShapeDtypeStruct((1, np_), jnp.int32),
         interpret=interpret,
-    )(node_ids_p[:, None], codes_lvl, table)
-    return out[:n, 0]
+    )(table, node_p, codes_t)
+    return out[0, :n]

@@ -41,18 +41,34 @@ class TreeArrays(NamedTuple):
 # step ① — histogram binning
 # --------------------------------------------------------------------------
 def histogram_ref(codes: Array, g: Array, h: Array, node_ids: Array,
-                  n_nodes: int, n_bins: int) -> Array:
+                  n_nodes: int, n_bins: int, init: Array = None) -> Array:
     """Scatter-add oracle: hist[node, f, bin] += (g, h).
 
     codes: (n, F) uint; g, h: (n,); node_ids: (n,) int32 in [0, n_nodes).
-    Returns (n_nodes, F, n_bins, 2) float32.
+    Returns (n_nodes, F, n_bins, 2) float32.  ``init``, an (n_nodes, F,
+    n_bins, 2) running histogram, is scattered INTO: the adds continue
+    its accumulation record by record, so accumulating chunks in order
+    rounds exactly like one scatter over all of them.
+
+    One field at a time, g and h apart: every scatter then takes an (n,)
+    update vector, where one scatter of (n, F, 2) updates would pad its
+    minor dim of 2 to the chip's 128 lanes.
     """
     n, F = codes.shape
-    stats = jnp.stack([g, h], axis=-1).astype(jnp.float32)          # (n, 2)
-    comb = node_ids.astype(jnp.int32)[:, None] * n_bins + codes.astype(jnp.int32)
-    hist = jnp.zeros((F, n_nodes * n_bins, 2), jnp.float32)
-    hist = hist.at[jnp.arange(F)[None, :], comb].add(stats[:, None, :])
-    return hist.reshape(F, n_nodes, n_bins, 2).transpose(1, 0, 2, 3)
+    g = g.astype(jnp.float32)
+    h = h.astype(jnp.float32)
+    base = node_ids.astype(jnp.int32) * n_bins
+    if init is None:
+        init = jnp.zeros((n_nodes, F, n_bins, 2), jnp.float32)
+    acc = init.transpose(1, 3, 0, 2).reshape(F, 2, n_nodes * n_bins)
+
+    def field(args):
+        col, a = args
+        comb = base + col.astype(jnp.int32)
+        return jnp.stack([a[0].at[comb].add(g), a[1].at[comb].add(h)])
+
+    hist = jax.lax.map(field, (codes.T, acc))             # (F, 2, NN*NB)
+    return hist.reshape(F, 2, n_nodes, n_bins).transpose(2, 0, 3, 1)
 
 
 def _decide_go_left(code: Array, feature: Array, threshold: Array,

@@ -1,7 +1,9 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-Targets TPU v5e:  197 TFLOP/s bf16 per chip, 819 GB/s HBM, ~50 GB/s/link
-ICI.  The compiled program produced by the SPMD partitioner is the
+Peaks come from a table keyed by ``jax.Device.device_kind``; a kind not in
+the table raises instead of borrowing another chip's numbers.  The
+dry-runs target TPU v5e (``"TPU v5 lite"``): 197 TFLOP/s bf16 per chip,
+819 GB/s HBM, ~50 GB/s/link ICI.  The compiled program produced by the SPMD partitioner is the
 *per-chip* program, so cost_analysis() FLOPs/bytes and the collective
 operand sizes parsed from the optimized HLO are per-chip quantities:
 
@@ -19,9 +21,21 @@ from __future__ import annotations
 import re
 from typing import Dict, List
 
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # bytes/s / chip
-LINK_BW = 50e9             # bytes/s / chip (ICI)
+# per-chip peaks (Google Cloud documentation, "TPU v5e"): bf16 FLOP/s,
+# HBM bytes/s, ICI bytes/s per link
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+DRYRUN_DEVICE_KIND = "TPU v5 lite"     # the chip the dry-runs model
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Published peaks of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r} (known: {sorted(PEAKS)})") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,
@@ -71,11 +85,13 @@ def collective_bytes(hlo_text: str) -> int:
 
 
 def roofline_terms(flops_per_chip: float, bytes_per_chip: float,
-                   coll_bytes_per_chip: float) -> Dict[str, float]:
+                   coll_bytes_per_chip: float,
+                   device_kind: str = DRYRUN_DEVICE_KIND) -> Dict[str, float]:
+    p = peaks(device_kind)
     terms = {
-        "compute_s": flops_per_chip / PEAK_FLOPS,
-        "memory_s": bytes_per_chip / HBM_BW,
-        "collective_s": coll_bytes_per_chip / LINK_BW,
+        "compute_s": flops_per_chip / p["flops"],
+        "memory_s": bytes_per_chip / p["hbm_bw"],
+        "collective_s": coll_bytes_per_chip / p["link_bw"],
     }
     dom = max(terms, key=terms.get)
     bound = max(terms.values())

@@ -236,6 +236,9 @@ def run_lm(args):
 
 def main():
     from repro.configs import ARCH_IDS
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="gbdt", choices=["gbdt", "lm"])

@@ -20,6 +20,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def run_gbdt(args):
     from repro.api import (BoosterClassifier, BoosterRegressor,
@@ -148,6 +150,7 @@ def run_lm(args):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="gbdt", choices=["gbdt", "lm"])
     ap.add_argument("--dataset", default="higgs")

@@ -1,20 +1,14 @@
 """Process-wide resilience counters.
 
-The perf gate needs to distinguish "slow" from "silently degraded" and
-"slow" from "spent the round budget recovering" — so every resilience
-event increments a named process-wide counter here, and the benchmark
-harness snapshots the counters around each lane
-(``benchmarks/run.py`` records per-lane ``degradations`` /
-``recoveries`` in the emitted JSON).
+The perf gate needs to distinguish "slow" from "spent the round budget
+recovering" — so every resilience event increments a named process-wide
+counter here, and the benchmark harness snapshots the counters around
+each lane (``benchmarks/run.py`` records per-lane ``recoveries`` in the
+emitted JSON).
 
-Two event families today:
-
-  * ``"degradations"`` — a Pallas kernel launch failed and the dispatch
-    demoted the plan's strategy to the jnp twin
-    (``repro.kernels.ops``);
-  * ``"recoveries"`` — a trainer recovery branch fired (transient
-    replay, OOM degradation, divergence rollback — streaming or
-    distributed).
+``"recoveries"`` counts every trainer recovery branch that fired
+(transient replay, OOM degradation, divergence rollback — streaming or
+distributed).  A failing Pallas kernel is not an event here: it raises.
 
 Counters are cumulative per process; use :func:`snapshot` around a
 region to attribute events to it.  Thread-safe (the serving daemon and

@@ -132,3 +132,23 @@ def test_warm_start_continues(reg_data):
     cont = train(cfg, tr, ytr, init_model=first.model)
     assert cont.model.n_trees == 8
     assert cont.history["train_loss"][-1] <= first.history["train_loss"][-1]
+
+
+@pytest.mark.parametrize("feature,want", [
+    # root splits; node 1 stops, so its records all sit in slot 0
+    ([3, -1, 5], [-2.0 / 4.0, -2.0 / 4.0, 1.0 / 2.0, -4.0 / 2.0]),
+    # the root stops: every record sits in slot 0
+    ([-1, -1, -1], [-2.0 / 4.0] * 4),
+])
+def test_early_leaf_weighed_from_record_sums(feature, want):
+    """A node that stops splitting is weighed from the per-record G/H sums
+    of the leftmost bottom slot of its subtree, where its records land,
+    and that weight fills every slot of the subtree."""
+    from repro.core.tree import settle_leaves
+    Gb = jnp.asarray([[2.0, 0.0, -1.0, 4.0]])
+    Hb = jnp.asarray([[3.0, 0.0, 1.0, 1.0]])
+    if feature[0] < 0:                    # no record left slot 0
+        Gb, Hb = Gb.at[0, 1:].set(0.0), Hb.at[0, 1:].set(0.0)
+    got = settle_leaves(Gb, Hb, jnp.asarray([feature], jnp.int32), 1.0)
+    np.testing.assert_array_equal(np.asarray(got)[0],
+                                  np.asarray(want, np.float32))

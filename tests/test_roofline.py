@@ -1,5 +1,6 @@
 """Roofline machinery unit tests (HLO collective parser + term math)."""
 import numpy as np
+import pytest
 
 from repro.launch import roofline as rl
 
@@ -42,6 +43,12 @@ def test_roofline_terms_and_dominance():
     np.testing.assert_allclose(t["roofline_fraction"], 1.0)
     t2 = rl.roofline_terms(1e12, 1e12, 1e12)
     assert t2["dominant"] == "collective"  # 20s > 1.2s > 5ms
+
+
+def test_roofline_peaks_refuse_unknown_device_kind():
+    assert rl.peaks("TPU v5 lite")["flops"] == 197e12
+    with pytest.raises(KeyError, match="no published peaks"):
+        rl.roofline_terms(1e12, 1e12, 0, device_kind="cpu")
 
 
 def test_model_flops_convention():

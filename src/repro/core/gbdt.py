@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.api.plan import ExecutionPlan
 from repro.core import binning as binning_mod
 from repro.core import losses as losses_mod
@@ -348,6 +349,20 @@ def _round_stats(config: GBDTConfig, tkey, g, h, n: int, F: int,
     return g, h, field_mask
 
 
+def _gradients(config: GBDTConfig, loss, margins, y, tkey, n: int, F: int,
+               K: Optional[int]):
+    """The round's filtered gradient statistics: (g, h, field_mask)."""
+    with tracing.scope(tracing.GRADIENTS):
+        g, h = loss.grad_hess(margins, y)
+        return _round_stats(config, tkey, g, h, n, F, K)
+
+
+def _mean_loss(loss, margins, y) -> jax.Array:
+    """The mean loss over the records, a device scalar."""
+    with tracing.scope(tracing.LOSS):
+        return jnp.mean(loss.value(margins, y))
+
+
 def _validate_multiclass_labels(K: int, y, eval_y=None) -> None:
     """An out-of-range class in either split would otherwise clamp inside
     the softmax loss (silent NaN loss / broken early stopping)."""
@@ -410,8 +425,8 @@ def _fused_round_step(config: GBDTConfig, plan: ExecutionPlan, n: int,
     with_eval = n_eval is not None
 
     def body(margins, y, tkey, codes, codes_cm, is_cat_field):
-        g, h = loss.grad_hess(margins, y)
-        g, h, field_mask = _round_stats(config, tkey, g, h, n, F, K)
+        g, h, field_mask = _gradients(config, loss, margins, y, tkey, n, F,
+                                      K)
         common = dict(depth=config.max_depth, n_bins=n_bins,
                       missing_bin=n_bins - 1, is_cat_field=is_cat_field,
                       field_mask=field_mask, lambda_=config.lambda_,
@@ -427,7 +442,7 @@ def _fused_round_step(config: GBDTConfig, plan: ExecutionPlan, n: int,
         delta = (_predict_forest(tree, data, plan) if K is not None
                  else _predict_one_tree(tree, data, plan))
         margins = margins + delta
-        return margins, tree, jnp.mean(loss.value(margins, y))
+        return margins, tree, _mean_loss(loss, margins, y)
 
     if not with_eval:
         step = body
@@ -443,7 +458,7 @@ def _fused_round_step(config: GBDTConfig, plan: ExecutionPlan, n: int,
                         if K is not None
                         else _predict_one_tree(tree, ev_data, plan))
             ev_margins = ev_margins + ev_delta
-            ev_loss = jnp.mean(loss.value(ev_margins, y_ev))
+            ev_loss = _mean_loss(loss, ev_margins, y_ev)
             return margins, ev_margins, tree, train_loss, ev_loss
         donate = (0, 1)
     # donation is a no-op (plus a warning) on the CPU backend — only ask
@@ -502,9 +517,6 @@ def train(config: GBDTConfig, data: BinnedDataset, y,
     history: Dict[str, List[float]] = {"train_loss": []}   # entries: (K,...)
     if eval_set is not None:
         history["eval_loss"] = []
-    step_times = {"binning_split": 0.0, "partition": 0.0, "traversal": 0.0,
-                  "other": 0.0}
-
     if init_model is not None:
         if K is not None:
             trees = _unstack_forests(init_model.trees, init_model.n_rounds,
@@ -533,99 +545,111 @@ def train(config: GBDTConfig, data: BinnedDataset, y,
 
     if config.fused_rounds:
         return _train_fused(config, plan, data, y, eval_set, trees, margins,
-                            eval_margins, base_margin, history, step_times,
-                            key, callback, verbose, n, F,
+                            eval_margins, base_margin, history, key,
+                            callback, verbose, n, F,
                             recovery=recovery, shutdown=shutdown)
 
+    step_times = dict.fromkeys(tracing.HOST_LOOP_KEYS + (tracing.SYNC_WAIT,),
+                               0.0)
     start = len(trees)
     for t_idx in range(start, start + config.n_trees):
-        tkey = jax.random.fold_in(key, t_idx)  # deterministic replay stream
-        t0 = time.perf_counter()
-        g, h = loss.grad_hess(margins, y)
-        g, h, field_mask = _round_stats(config, tkey, g, h, n, F, K)
+        with tracing.round_span(t_idx):
+            tkey = jax.random.fold_in(key, t_idx)  # deterministic replay
+            with tracing.span(tracing.GROW, step_times,
+                              tracing.BINNING_SPLIT):
+                with tracing.span(tracing.GRADIENTS):
+                    g, h, field_mask = _gradients(config, loss, margins, y,
+                                                  tkey, n, F, K)
+                common = dict(depth=depth, n_bins=data.n_bins,
+                              missing_bin=data.missing_bin,
+                              is_cat_field=data.is_categorical,
+                              field_mask=field_mask, lambda_=config.lambda_,
+                              gamma=config.gamma,
+                              min_child_weight=config.min_child_weight,
+                              plan=plan)
+                if K is not None:
+                    # one class-batched pass grows all K per-class trees
+                    tree = tree_mod.fit_forest(data.codes, data.codes_cm,
+                                               g.T, h.T, **common)
+                elif config.grow_policy == "depthwise":
+                    tree = tree_mod.fit_tree(data.codes, data.codes_cm, g, h,
+                                             **common)
+                else:
+                    tree = tree_mod.fit_tree_lossguide(
+                        data.codes, data.codes_cm, g, h,
+                        max_leaves=config.max_leaves, **common)
+                # shrinkage is folded into the stored leaf values so a tree
+                # is self-contained (predict == sum of tree outputs)
+                tree = shrink(tree, config.learning_rate)
+                with tracing.sync("tree", step_times):
+                    tree = jax.tree.map(jax.block_until_ready, tree)
 
-        common = dict(depth=depth, n_bins=data.n_bins,
-                      missing_bin=data.missing_bin,
-                      is_cat_field=data.is_categorical,
-                      field_mask=field_mask, lambda_=config.lambda_,
-                      gamma=config.gamma,
-                      min_child_weight=config.min_child_weight, plan=plan)
-        if K is not None:
-            # one class-batched pass grows all K per-class trees
-            tree = tree_mod.fit_forest(data.codes, data.codes_cm,
-                                       g.T, h.T, **common)
-        elif config.grow_policy == "depthwise":
-            tree = tree_mod.fit_tree(data.codes, data.codes_cm, g, h,
-                                     **common)
-        else:
-            tree = tree_mod.fit_tree_lossguide(
-                data.codes, data.codes_cm, g, h,
-                max_leaves=config.max_leaves, **common)
-        # shrinkage is folded into the stored leaf values so a tree is
-        # self-contained (predict == sum of tree outputs, XGBoost-style)
-        tree = shrink(tree, config.learning_rate)
-        tree = jax.tree.map(jax.block_until_ready, tree)
-        t1 = time.perf_counter()
-        step_times["binning_split"] += t1 - t0
+            # step ⑤ — one-tree traversal refreshes margins (and thus g, h)
+            with tracing.span(tracing.MARGIN_UPDATE, step_times,
+                              tracing.TRAVERSAL):
+                if K is not None:
+                    delta = _predict_forest(tree, data, plan)      # (n, K)
+                else:
+                    delta = _predict_one_tree(tree, data, plan)
+                margins = margins + delta
+                with tracing.sync("margins", step_times):
+                    margins.block_until_ready()
 
-        # step ⑤ — one-tree traversal refreshes margins (and thus g, h)
-        if K is not None:
-            delta = _predict_forest(tree, data, plan)          # (n, K)
-        else:
-            delta = _predict_one_tree(tree, data, plan)
-        margins = margins + delta
-        margins.block_until_ready()
-        t2 = time.perf_counter()
-        step_times["traversal"] += t2 - t1
+            trees.append(tree)
+            with tracing.span(tracing.LOSS, step_times, tracing.OTHER):
+                train_loss = _mean_loss(loss, margins, y)
+                with tracing.sync("loss", step_times):
+                    train_loss = float(train_loss)
+                history["train_loss"].append(train_loss)
+            if eval_set is not None:
+                with tracing.span(tracing.EVAL, step_times, tracing.OTHER):
+                    if K is not None:
+                        ev_delta = _predict_forest(tree, eval_set[0], plan)
+                    else:
+                        ev_delta = _predict_one_tree(tree, eval_set[0], plan)
+                    eval_margins = eval_margins + ev_delta
+                    ev = _mean_loss(loss, eval_margins,
+                                    jnp.asarray(eval_set[1], jnp.float32))
+                    with tracing.sync("eval", step_times):
+                        ev = float(ev)
+                    history["eval_loss"].append(ev)
 
-        trees.append(tree)
-        train_loss = float(jnp.mean(loss.value(margins, y)))
-        history["train_loss"].append(train_loss)
-
-        if eval_set is not None:
-            if K is not None:
-                ev_delta = _predict_forest(tree, eval_set[0], plan)
-            else:
-                ev_delta = _predict_one_tree(tree, eval_set[0], plan)
-            eval_margins = eval_margins + ev_delta
-            ev = float(jnp.mean(loss.value(eval_margins,
-                                           jnp.asarray(eval_set[1],
-                                                       jnp.float32))))
-            history["eval_loss"].append(ev)
-            if ev < best_eval - 1e-12:
-                best_eval, best_round = ev, t_idx
-            if (config.early_stopping_rounds is not None
-                    and t_idx - best_round >= config.early_stopping_rounds):
-                if verbose:
-                    print(f"[gbdt] early stop at tree {t_idx} "
-                          f"(best {best_round}: {best_eval:.6f})")
-                break
-        step_times["other"] += time.perf_counter() - t2
-
-        if verbose and (t_idx % config.log_every == 0
-                        or t_idx == start + config.n_trees - 1):
-            print(f"[gbdt] tree {t_idx:4d}  train_loss={train_loss:.6f}")
-        # divergence sentinel: the host loop already syncs the loss each
-        # round, so the finiteness check is free; the rollback machinery
-        # lives in the fused/distributed engines — here the sentinel is
-        # fail-fast-but-typed
-        if recovery is not None and not np.isfinite(train_loss):
-            raise NumericalDivergenceError(
-                f"non-finite training loss at round {t_idx}",
-                round_index=t_idx, what="loss")
-        if callback is not None:
-            callback(t_idx, _as_model(trees, base_margin, config,
-                                      data.missing_bin, F))
-        if shutdown is not None and shutdown.requested:
-            partial = TrainResult(
-                model=_as_model(trees, base_margin, config,
-                                data.missing_bin, F),
-                history=history, step_times=step_times,
-                stats={"n_rows": n, "interrupted": True})
-            raise TrainingInterrupted(
-                f"shutdown ({shutdown.signal_name}) after round {t_idx}",
-                rounds_done=len(trees), signal_name=shutdown.signal_name,
-                result=partial)
+            with tracing.span(tracing.COMMIT):
+                if eval_set is not None:
+                    if ev < best_eval - 1e-12:
+                        best_eval, best_round = ev, t_idx
+                    if (config.early_stopping_rounds is not None
+                            and t_idx - best_round
+                            >= config.early_stopping_rounds):
+                        if verbose:
+                            print(f"[gbdt] early stop at tree {t_idx} "
+                                  f"(best {best_round}: {best_eval:.6f})")
+                        break
+                if verbose and (t_idx % config.log_every == 0
+                                or t_idx == start + config.n_trees - 1):
+                    print(f"[gbdt] tree {t_idx:4d}  "
+                          f"train_loss={train_loss:.6f}")
+                # divergence sentinel: the host loop already syncs the loss
+                # each round, so the finiteness check is free; the rollback
+                # machinery lives in the fused/distributed engines — here
+                # the sentinel is fail-fast-but-typed
+                if recovery is not None and not np.isfinite(train_loss):
+                    raise NumericalDivergenceError(
+                        f"non-finite training loss at round {t_idx}",
+                        round_index=t_idx, what="loss")
+                if callback is not None:
+                    callback(t_idx, _as_model(trees, base_margin, config,
+                                              data.missing_bin, F))
+                if shutdown is not None and shutdown.requested:
+                    partial = TrainResult(
+                        model=_as_model(trees, base_margin, config,
+                                        data.missing_bin, F),
+                        history=history, step_times=step_times,
+                        stats={"n_rows": n, "interrupted": True})
+                    raise TrainingInterrupted(
+                        f"shutdown ({shutdown.signal_name}) after round "
+                        f"{t_idx}", rounds_done=len(trees),
+                        signal_name=shutdown.signal_name, result=partial)
 
     return TrainResult(model=_as_model(trees, base_margin, config,
                                        data.missing_bin, F),
@@ -634,18 +658,19 @@ def train(config: GBDTConfig, data: BinnedDataset, y,
 
 
 def _train_fused(config, plan, data, y, eval_set, trees, margins,
-                 eval_margins, base_margin, history, step_times, key,
-                 callback, verbose, n, F, recovery=None,
-                 shutdown=None) -> TrainResult:
+                 eval_margins, base_margin, history, key, callback, verbose,
+                 n, F, recovery=None, shutdown=None) -> TrainResult:
     """The device-resident boosting loop: one jitted dispatch per round.
 
     The host never synchronizes on per-round values unless it has to —
     losses stay device scalars, fetched every ``config.log_every`` rounds
     for verbose logging and once in bulk at the end.  Early stopping is
     the one per-round consumer: it pulls the eval scalar each round
-    (still a single dispatch per round).  Per-step attribution is not
-    possible inside a fused round, so wall time lands in a dedicated
-    ``fused_rounds`` slot of ``step_times``.
+    (still a single dispatch per round).  The host clock sees one round
+    program, so wall time lands in a ``fused_rounds`` slot of
+    ``step_times`` (with its ``sync_wait`` sub-total); the round body
+    carries the host loop's device scopes, so a profiler trace still
+    splits the round's device time by step.
 
     Divergence sentinel: every ``config.log_every`` rounds one device-side
     ``isfinite`` reduction over (loss, margins) is synced to the host.  A
@@ -663,6 +688,7 @@ def _train_fused(config, plan, data, y, eval_set, trees, margins,
                              data.n_bins, n_eval)
     y_ev = (jnp.asarray(eval_set[1], jnp.float32)
             if eval_set is not None else None)
+    step_times = {tracing.FUSED_ROUNDS: 0.0, tracing.SYNC_WAIT: 0.0}
     train_dev: List[jax.Array] = []
     eval_dev: List[jax.Array] = []
     best_eval, best_round = np.inf, -1
@@ -673,103 +699,121 @@ def _train_fused(config, plan, data, y, eval_set, trees, margins,
 
     def _flush_history():
         # one bulk fetch materializes the whole loss trajectory
-        history["train_loss"].extend(float(v)
-                                     for v in jax.device_get(train_dev))
-        if eval_set is not None:
-            history["eval_loss"].extend(float(v)
-                                        for v in jax.device_get(eval_dev))
-        step_times["fused_rounds"] = time.perf_counter() - t_loop
+        with tracing.sync("history", step_times):
+            history["train_loss"].extend(float(v)
+                                         for v in jax.device_get(train_dev))
+            if eval_set is not None:
+                history["eval_loss"].extend(float(v)
+                                            for v in jax.device_get(eval_dev))
+        step_times[tracing.FUSED_ROUNDS] = time.perf_counter() - t_loop
 
     def _snap(t_next):
         """Host copy of the resumable loop state (taken only at finite
         sentinel checks, so a rollback always lands on finite state)."""
-        return {"t": t_next, "trees": len(trees), "dev": len(train_dev),
-                "margins": np.asarray(margins),
-                "eval": (None if eval_margins is None
-                         else np.asarray(eval_margins)),
-                "best": (best_eval, best_round)}
+        with tracing.sync("snapshot", step_times):
+            return {"t": t_next, "trees": len(trees), "dev": len(train_dev),
+                    "margins": np.asarray(margins),
+                    "eval": (None if eval_margins is None
+                             else np.asarray(eval_margins)),
+                    "best": (best_eval, best_round)}
 
     snap = _snap(start)
     diverged_at = -1                   # sentinel window of the last trip
     t_idx = start
     stop_early = False
     while t_idx < end and not stop_early:
-        tkey = jax.random.fold_in(key, t_idx)   # same stream as host loop
-        if eval_set is None:
-            margins, tree, tl = step(margins, y, tkey, data.codes,
-                                     data.codes_cm, data.is_categorical)
-        else:
-            margins, eval_margins, tree, tl, ev = step(
-                margins, eval_margins, y, y_ev, tkey, data.codes,
-                data.codes_cm, eval_set[0].codes, eval_set[0].codes_cm,
-                data.is_categorical)
-            eval_dev.append(ev)
-        trees.append(tree)
-        train_dev.append(tl)
-        if eval_set is not None and config.early_stopping_rounds is not None:
-            ev_f = float(ev)                    # the one per-round sync
-            if ev_f < best_eval - 1e-12:
-                best_eval, best_round = ev_f, t_idx
-            if t_idx - best_round >= config.early_stopping_rounds:
-                if verbose:
-                    print(f"[gbdt] early stop at tree {t_idx} "
-                          f"(best {best_round}: {best_eval:.6f})")
-                stop_early = True
-        if verbose and (t_idx % config.log_every == 0 or t_idx == end - 1):
-            print(f"[gbdt] tree {t_idx:4d}  train_loss={float(tl):.6f}")
+        with tracing.round_span(t_idx):
+            tkey = jax.random.fold_in(key, t_idx)   # host loop's stream
+            with tracing.span(tracing.DISPATCH):
+                if eval_set is None:
+                    margins, tree, tl = step(margins, y, tkey, data.codes,
+                                             data.codes_cm,
+                                             data.is_categorical)
+                else:
+                    margins, eval_margins, tree, tl, ev = step(
+                        margins, eval_margins, y, y_ev, tkey, data.codes,
+                        data.codes_cm, eval_set[0].codes,
+                        eval_set[0].codes_cm, data.is_categorical)
+            with tracing.span(tracing.COMMIT):
+                if eval_set is not None:
+                    eval_dev.append(ev)
+                trees.append(tree)
+                train_dev.append(tl)
+                if (eval_set is not None
+                        and config.early_stopping_rounds is not None):
+                    with tracing.sync("eval", step_times):
+                        ev_f = float(ev)        # the one per-round sync
+                    if ev_f < best_eval - 1e-12:
+                        best_eval, best_round = ev_f, t_idx
+                    if t_idx - best_round >= config.early_stopping_rounds:
+                        if verbose:
+                            print(f"[gbdt] early stop at tree {t_idx} "
+                                  f"(best {best_round}: {best_eval:.6f})")
+                        stop_early = True
+                if verbose and (t_idx % config.log_every == 0
+                                or t_idx == end - 1):
+                    with tracing.sync("loss", step_times):
+                        tl_f = float(tl)
+                    print(f"[gbdt] tree {t_idx:4d}  train_loss={tl_f:.6f}")
 
-        # ---- divergence sentinel (one fused device reduction + sync)
-        if t_idx % config.log_every == 0 or t_idx == end - 1 or stop_early:
-            finite = bool(jnp.isfinite(tl) & jnp.all(jnp.isfinite(margins)))
-            if not finite:
-                if (recovery is None or rstats["divergence_rollbacks"]
-                        >= recovery.max_divergence_rollbacks):
-                    raise NumericalDivergenceError(
-                        f"non-finite loss/margins at round {t_idx}",
-                        round_index=t_idx, what="loss/margins")
-                rstats["divergence_rollbacks"] += 1
-                _metrics.record("recoveries")
-                del trees[snap["trees"]:]
-                del train_dev[snap["dev"]:]
-                del eval_dev[snap["dev"]:]
-                margins = jnp.asarray(snap["margins"])
-                eval_margins = (None if snap["eval"] is None
-                                else jnp.asarray(snap["eval"]))
-                best_eval, best_round = snap["best"]
-                if diverged_at == snap["t"]:
-                    # the same window diverged on its replay: genuine
-                    # divergence, not a glitch — shrink the steps
-                    live = dataclasses.replace(
-                        live, learning_rate=(live.learning_rate
-                                             * recovery.divergence_backoff))
-                    step = _fused_round_step(_fused_step_key(live), plan,
-                                             n, F, data.n_bins, n_eval)
-                    if verbose:
-                        print(f"[gbdt] round {snap['t']} diverged twice; "
-                              f"learning_rate -> {live.learning_rate:g}")
-                elif verbose:
-                    print(f"[gbdt] divergence at round {t_idx}; rolling "
-                          f"back to round {snap['t']}")
-                diverged_at = snap["t"]
-                t_idx = snap["t"]
-                stop_early = False
-                continue
-            snap = _snap(t_idx + 1)
-        if callback is not None:
-            callback(t_idx, _as_model(trees, base_margin, config,
-                                      data.missing_bin, F))
-        if shutdown is not None and shutdown.requested:
-            _flush_history()
-            partial = TrainResult(
-                model=_as_model(trees, base_margin, config,
-                                data.missing_bin, F),
-                history=history, step_times=step_times,
-                stats={"n_rows": n, "fused_rounds": True,
-                       "interrupted": True, **rstats})
-            raise TrainingInterrupted(
-                f"shutdown ({shutdown.signal_name}) after round {t_idx}",
-                rounds_done=len(trees), signal_name=shutdown.signal_name,
-                result=partial)
+                # ---- divergence sentinel (one fused device reduction + sync)
+                if (t_idx % config.log_every == 0 or t_idx == end - 1
+                        or stop_early):
+                    finite = jnp.isfinite(tl) & jnp.all(jnp.isfinite(margins))
+                    with tracing.sync("sentinel", step_times):
+                        finite = bool(finite)
+                    if not finite:
+                        if (recovery is None or rstats["divergence_rollbacks"]
+                                >= recovery.max_divergence_rollbacks):
+                            raise NumericalDivergenceError(
+                                f"non-finite loss/margins at round {t_idx}",
+                                round_index=t_idx, what="loss/margins")
+                        rstats["divergence_rollbacks"] += 1
+                        _metrics.record("recoveries")
+                        del trees[snap["trees"]:]
+                        del train_dev[snap["dev"]:]
+                        del eval_dev[snap["dev"]:]
+                        margins = jnp.asarray(snap["margins"])
+                        eval_margins = (None if snap["eval"] is None
+                                        else jnp.asarray(snap["eval"]))
+                        best_eval, best_round = snap["best"]
+                        if diverged_at == snap["t"]:
+                            # the same window diverged on its replay: genuine
+                            # divergence, not a glitch — shrink the steps
+                            live = dataclasses.replace(
+                                live, learning_rate=(
+                                    live.learning_rate
+                                    * recovery.divergence_backoff))
+                            step = _fused_round_step(
+                                _fused_step_key(live), plan, n, F,
+                                data.n_bins, n_eval)
+                            if verbose:
+                                print(f"[gbdt] round {snap['t']} diverged "
+                                      f"twice; learning_rate -> "
+                                      f"{live.learning_rate:g}")
+                        elif verbose:
+                            print(f"[gbdt] divergence at round {t_idx}; "
+                                  f"rolling back to round {snap['t']}")
+                        diverged_at = snap["t"]
+                        t_idx = snap["t"]
+                        stop_early = False
+                        continue
+                    snap = _snap(t_idx + 1)
+                if callback is not None:
+                    callback(t_idx, _as_model(trees, base_margin, config,
+                                              data.missing_bin, F))
+                if shutdown is not None and shutdown.requested:
+                    _flush_history()
+                    partial = TrainResult(
+                        model=_as_model(trees, base_margin, config,
+                                        data.missing_bin, F),
+                        history=history, step_times=step_times,
+                        stats={"n_rows": n, "fused_rounds": True,
+                               "interrupted": True, **rstats})
+                    raise TrainingInterrupted(
+                        f"shutdown ({shutdown.signal_name}) after round "
+                        f"{t_idx}", rounds_done=len(trees),
+                        signal_name=shutdown.signal_name, result=partial)
         t_idx += 1
     _flush_history()
     jax.block_until_ready(margins)
@@ -790,7 +834,21 @@ def _as_model(trees, base_margin, config, missing_bin, F) -> GBDTModel:
 
 def _predict_one_tree(tree: TreeArrays, data: BinnedDataset,
                       plan: ExecutionPlan) -> jax.Array:
-    """Step-⑤ traversal, using the paper's renumbered-column fetch when it
+    """Step-⑤ traversal of one tree -> (n,) deltas."""
+    with tracing.scope(tracing.STEP5):
+        return _traverse(tree, data, plan)
+
+
+def _predict_forest(forest: TreeArrays, data: BinnedDataset,
+                    plan: ExecutionPlan) -> jax.Array:
+    """Step-⑤ traversal of one round's K per-class trees -> (n, K) deltas."""
+    with tracing.scope(tracing.STEP5):
+        return jax.vmap(lambda t: _traverse(t, data, plan))(forest).T
+
+
+def _traverse(tree: TreeArrays, data: BinnedDataset,
+              plan: ExecutionPlan) -> jax.Array:
+    """One tree's walk, using the paper's renumbered-column fetch when it
     saves bandwidth: a depth-D tree touches ≤ 2^D − 1 columns, so for wide
     datasets only those columns are gathered from the column-major copy."""
     n_int = tree.feature.shape[0]
@@ -808,13 +866,6 @@ def _predict_one_tree(tree: TreeArrays, data: BinnedDataset,
                                  missing_bin=data.missing_bin, plan=plan)
     return ops.traverse_tree(tree, data.codes, missing_bin=data.missing_bin,
                              plan=plan)
-
-
-def _predict_forest(forest: TreeArrays, data: BinnedDataset,
-                    plan: ExecutionPlan) -> jax.Array:
-    """Step-⑤ traversal of one round's K per-class trees -> (n, K) deltas."""
-    delta = jax.vmap(lambda t: _predict_one_tree(t, data, plan))(forest)
-    return delta.T
 
 
 def _replay_margins(model: GBDTModel, data: BinnedDataset,
@@ -1019,8 +1070,7 @@ def train_streaming(config: GBDTConfig, source, binner, y, *,
     history: Dict[str, List[float]] = {"train_loss": []}
     if eval_set is not None:
         history["eval_loss"] = []
-    step_times = {"binning_split": 0.0, "partition": 0.0, "traversal": 0.0,
-                  "other": 0.0}
+    step_times = dict.fromkeys(tracing.HOST_LOOP_KEYS, 0.0)
 
     if init_model is not None:
         if K is not None:
@@ -1184,8 +1234,8 @@ def train_streaming(config: GBDTConfig, source, binner, y, *,
                 raise
 
             # ---- commit: the round succeeded, mutate state atomically
-            step_times["binning_split"] += t1 - t0
-            step_times["traversal"] += t2 - t1
+            step_times[tracing.BINNING_SPLIT] += t1 - t0
+            step_times[tracing.TRAVERSAL] += t2 - t1
             margins = new_margins
             trees.append(tree)
             train_loss = float(jnp.mean(loss.value(margins, y)))
@@ -1204,7 +1254,7 @@ def train_streaming(config: GBDTConfig, source, binner, y, *,
                         print(f"[gbdt] early stop at tree {t_idx} "
                               f"(best {best_round}: {best_eval:.6f})")
                     stop_early = True
-            step_times["other"] += time.perf_counter() - t2
+            step_times[tracing.OTHER] += time.perf_counter() - t2
 
             if verbose and (t_idx % config.log_every == 0
                             or t_idx == end - 1):

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.api.plan import ExecutionPlan, resolve_plan
 from repro.core import splits as splits_mod
 from repro.core.binning import PackedCodes
@@ -165,28 +166,32 @@ def _fit_forest_jit(codes, codes_cm, g, h, *, depth: int, n_bins: int,
         # step ① — one batched pass covers all K class partitions; with
         # plan.hist_subtraction, levels > 0 bin only the smaller child of
         # each parent and derive the sibling from the previous level's hist
-        if plan.hist_subtraction and level > 0:
-            hist = _subtract_level_hist(codes, g, h, node_ids, prev_hist,
-                                        n_nodes=nn, n_bins=n_bins, plan=plan)
-        else:
-            hist = ops.build_histogram(codes, g, h, node_ids, n_nodes=nn,
-                                       n_bins=n_bins, plan=plan)
+        with tracing.scope(tracing.STEP1, level):
+            if plan.hist_subtraction and level > 0:
+                hist = _subtract_level_hist(codes, g, h, node_ids, prev_hist,
+                                            n_nodes=nn, n_bins=n_bins,
+                                            plan=plan)
+            else:
+                hist = ops.build_histogram(codes, g, h, node_ids, n_nodes=nn,
+                                           n_bins=n_bins, plan=plan)
         prev_hist = hist                                      # (K,nn,F,NB,2)
         # step ② — split decisions + tree-table updates (shared with the
         # chunked grower, which accumulates the same hist across chunks)
-        state, best, do_split = _decide_level(
-            hist, level, state, is_cat_field, field_mask, lambda_,
-            gamma, min_child_weight, find)
+        with tracing.scope(tracing.STEP2, level):
+            state, best, do_split = _decide_level(
+                hist, level, state, is_cat_field, field_mask, lambda_,
+                gamma, min_child_weight, find)
 
         # step ③ — per-class predicate columns from the column-major copy
-        codes_lvl = _gather_fields(
-            codes_cm, jnp.where(do_split, best.feature, 0))     # (K,nn,n)
-        node_ids = part(
-            node_ids, codes_lvl.transpose(0, 2, 1),
-            jnp.where(do_split,
-                      jnp.broadcast_to(jnp.arange(nn, dtype=jnp.int32),
-                                       (K, nn)), -1),
-            best.threshold, best.is_cat, best.default_left)
+        with tracing.scope(tracing.STEP3, level):
+            codes_lvl = _gather_fields(
+                codes_cm, jnp.where(do_split, best.feature, 0))  # (K,nn,n)
+            node_ids = part(
+                node_ids, codes_lvl.transpose(0, 2, 1),
+                jnp.where(do_split,
+                          jnp.broadcast_to(jnp.arange(nn, dtype=jnp.int32),
+                                           (K, nn)), -1),
+                best.threshold, best.is_cat, best.default_left)
 
     return state, node_ids
 
@@ -297,8 +302,9 @@ def settle_leaves(Gb, Hb, feature, lambda_):
 def _settle_jit(g, h, node_ids, feature, lambda_):
     """Leaf weights of every bottom slot from the final node ids."""
     n_leaf = feature.shape[1] + 1
-    return settle_leaves(leaf_sums(g, node_ids, n_leaf),
-                         leaf_sums(h, node_ids, n_leaf), feature, lambda_)
+    with tracing.scope(tracing.STEP4):
+        return settle_leaves(leaf_sums(g, node_ids, n_leaf),
+                             leaf_sums(h, node_ids, n_leaf), feature, lambda_)
 
 
 # --------------------------------------------------------------------------

@@ -223,6 +223,7 @@ def histogram_pallas(codes, g, h, node_ids, *, n_nodes: int, n_bins: int,
                                lambda fi, ri: (fi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((Fp, n_rows, n_bins), jnp.float32),
         interpret=interpret,
+        name="histogram_pallas",
     )(code_op, node2, g2, h2)
 
     hist = out[:F, :K * n_nodes * 2].reshape(F, K, n_nodes, 2, n_bins)

@@ -85,5 +85,6 @@ def partition_pallas(node_ids, codes_lvl, split_feature, split_threshold,
         out_specs=pl.BlockSpec((1, rblk), lambda ri: (0, ri)),
         out_shape=jax.ShapeDtypeStruct((1, np_), jnp.int32),
         interpret=interpret,
+        name="partition_pallas",
     )(table, node_p, codes_t)
     return out[0, :n]

@@ -163,6 +163,7 @@ def predict_ensemble_pallas(trees: TreeArrays, codes, *, missing_bin: int,
         out_specs=pl.BlockSpec((n_classes, rblk), lambda ri, ti: (0, ri)),
         out_shape=jax.ShapeDtypeStruct((n_classes, np_), jnp.float32),
         interpret=interpret,
+        name="predict_ensemble_pallas",
     )(codes_t, pack_node_table(trees),
       trees.leaf_value.astype(jnp.float32))
     return out[0, :n] if n_classes == 1 else out[:, :n].T

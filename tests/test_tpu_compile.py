@@ -133,3 +133,35 @@ def test_sharded_round_compiles(topo):
     for name in ("histogram_pallas", "partition_pallas"):
         assert sum(name in k for k in kernels) == depth, (name, kernels)
     assert "all-reduce" in text
+
+
+def test_grower_kernels_keep_their_names_under_step_scopes(one_chip):
+    """The one-chip grower at higgs widths: each level's kernels are ops
+    named after the kernel (the ``pallas_call`` name), whatever wraps the
+    call, and they and the split search carry their step scope in the op
+    metadata a trace reads."""
+    from repro import tracing
+    from repro.core import tree as tree_mod
+    depth, n, fields, bins = 6, 65536, 28, 256
+    plan = ExecutionPlan(hist_strategy="pallas_grouped",
+                         partition_strategy="pallas",
+                         traversal_strategy="pallas",
+                         interpret=False).resolved()
+    stat = _spec(one_chip, (1, n), jnp.float32)
+    mask = _spec(one_chip, (fields,), jnp.bool_)
+    text = tree_mod._fit_forest_jit.lower(
+        _spec(one_chip, (n, fields), jnp.uint8),
+        _spec(one_chip, (fields, n), jnp.uint8), stat, stat, depth=depth,
+        n_bins=bins, missing_bin=bins - 1, is_cat_field=mask,
+        field_mask=mask, lambda_=1.0, gamma=0.0, min_child_weight=1.0,
+        plan=plan).compile().as_text()
+    kernels = re.findall(r"%([\w.]+) = [^\n]*"
+                         r'custom_call_target="tpu_custom_call"[^\n]*'
+                         r'op_name="([^"]*)"', text)
+    for kernel, scope in (("histogram_pallas", tracing.STEP1),
+                          ("partition_pallas", tracing.STEP3)):
+        stacks = [stack for name, stack in kernels
+                  if re.fullmatch(rf"{kernel}\.\d+", name)]
+        assert sorted(re.search(rf"/{scope}/level(\d)/", s).group(1)
+                      for s in stacks) == [str(lv) for lv in range(depth)]
+    assert f"/{tracing.STEP2}/level{depth - 1}/" in text

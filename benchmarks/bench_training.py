@@ -54,11 +54,9 @@ def modeled_training_time(machine, n, F, depth=6, n_trees=1,
 
 
 def run_e2e(scale: float = 1.0, depth: int = 6, n_trees: int = 5):
-    """End-to-end depth-6 training rows/sec: the pre-PR path (direct
-    histograms, host-driven loop) vs hist-subtraction + fused rounds —
-    the acceptance comparison for the device-resident trainer (subtraction
-    halves step-① work at levels > 0, fused rounds drop the per-round
-    host syncs)."""
+    """End-to-end depth-6 training rows/sec: direct histograms vs
+    hist-subtraction (which halves step-① work at levels > 0), both
+    through the one-program-per-round trainer."""
     n = max(4000, int(40000 * scale))
     X, y, cats = make_tabular(n, 20, 4, n_cats=10, task="regression",
                               seed=0)
@@ -66,15 +64,13 @@ def run_e2e(scale: float = 1.0, depth: int = 6, n_trees: int = 5):
     rows = []
     rps = {}
     lanes = {
-        "direct": (ExecutionPlan(hist_strategy="scatter").resolved(), False),
-        "subfused": (ExecutionPlan(hist_strategy="scatter",
-                                   hist_subtraction=True).resolved(), True),
+        "direct": ExecutionPlan(hist_strategy="scatter").resolved(),
+        "subfused": ExecutionPlan(hist_strategy="scatter",
+                                  hist_subtraction=True).resolved(),
     }
-    for name, (plan, fused) in lanes.items():
-        cfg = GBDTConfig(n_trees=n_trees, max_depth=depth,
-                         learning_rate=0.3, fused_rounds=fused)
-        t = time_call(lambda cfg=cfg, plan=plan: train(cfg, data, y,
-                                                       plan=plan),
+    cfg = GBDTConfig(n_trees=n_trees, max_depth=depth, learning_rate=0.3)
+    for name, plan in lanes.items():
+        t = time_call(lambda plan=plan: train(cfg, data, y, plan=plan),
                       repeat=2)
         rps[name] = n * n_trees / t
         rows.append(csv_row(f"train_e2e_d{depth}_{name}", t * 1e6,
@@ -247,7 +243,7 @@ def run(scale: float = 1.0, max_bins: int = 128):
                             f"x={float(np.exp(np.mean(np.log(v)))):.2f}"))
     # (c) packed vs unpacked Pallas histogram kernel (4-bit nibble codes)
     rows.extend(run_packed_hist(scale=scale))
-    # (d) end-to-end depth-6 trainer: direct vs subtraction + fused rounds
+    # (d) end-to-end depth-6 trainer: direct vs subtraction histograms
     rows.extend(run_e2e(scale=scale))
     # (e) the distributed engine: 1-shard vs all-device data mesh
     rows.extend(run_distributed(scale=scale))

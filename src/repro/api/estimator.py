@@ -63,8 +63,7 @@ _PARAM_DEFAULTS: Dict[str, Any] = dict(
     n_trees=100, max_depth=6, learning_rate=0.1, lambda_=1.0, gamma=0.0,
     min_child_weight=1.0, objective=None, subsample=1.0,
     colsample_bytree=1.0, goss_top_rate=0.0, goss_other_rate=0.0,
-    grow_policy="depthwise", max_leaves=None, fused_rounds=False,
-    log_every=10,
+    grow_policy="depthwise", max_leaves=None, log_every=10,
     early_stopping_rounds=None, max_bins=256, categorical_fields=None,
     sketch_size=32768, n_classes=None, seed=0, plan=None)
 
@@ -203,7 +202,7 @@ class BoosterEstimator:
             goss_top_rate=self.goss_top_rate,
             goss_other_rate=self.goss_other_rate,
             grow_policy=self.grow_policy, max_leaves=self.max_leaves,
-            fused_rounds=self.fused_rounds, log_every=self.log_every,
+            log_every=self.log_every,
             early_stopping_rounds=self.early_stopping_rounds,
             n_classes=n_classes,
             seed=self.seed)
@@ -634,7 +633,10 @@ class BoosterEstimator:
         klass = {c.__name__: c for c in (BoosterRegressor,
                                          BoosterClassifier)}.get(
             est_meta.get("class"), cls)
-        est = klass(**est_meta.get("params", {}))
+        params = dict(est_meta.get("params", {}))
+        # bundles saved while rounds had an opt-in knob still name it
+        params.pop("fused_rounds", None)
+        est = klass(**params)
         est._model, est._binner = model, binner
         return est
 

@@ -3,9 +3,9 @@
 The outer loop follows Table I of the paper: grow trees one at a time
 (step ⑥), each tree level-by-level (steps ①–④), then pass every record
 through the finished tree to refresh its gradient statistics and the total
-loss (step ⑤).  The loop is host-driven; each step body is a jitted JAX
-function, so the same trainer runs single-device (this container) or under
-a pjit mesh (``repro.distributed``).
+loss (step ⑤).  A depthwise round is one jitted program, dispatched once
+per round; lossguide growth is host-driven.  The same round runs
+single-device or data-parallel over a mesh (``repro.distributed``).
 """
 from __future__ import annotations
 
@@ -52,11 +52,7 @@ class GBDTConfig:
     goss_other_rate: float = 0.0     # GOSS: sampled fraction of the rest
     grow_policy: str = "depthwise"   # "depthwise" | "lossguide"
     max_leaves: Optional[int] = None  # lossguide only
-    fused_rounds: bool = False       # one jitted step per boosting round:
-    #                                  grow + leaf settle + margin update +
-    #                                  loss accumulate, margins donated,
-    #                                  history fetched every log_every rounds
-    log_every: int = 10              # host-fetch / verbose cadence (rounds)
+    log_every: int = 10              # verbose / divergence-check cadence
     # deprecated per-step strategy strings — one release path; set an
     # ExecutionPlan (train(plan=...) / fit(plan=...)) instead
     hist_strategy: str = "auto"      # see repro.api.plan.HIST_STRATEGIES
@@ -84,9 +80,6 @@ class GBDTConfig:
             raise ValueError(f"unknown grow_policy {self.grow_policy!r}")
         if self.log_every < 1:
             raise ValueError("log_every must be >= 1")
-        if self.fused_rounds and self.grow_policy != "depthwise":
-            raise ValueError("fused_rounds requires the depthwise "
-                             "grow_policy (lossguide growth is host-driven)")
         if self.goss_top_rate or self.goss_other_rate:
             if not (0.0 <= self.goss_top_rate < 1.0
                     and 0.0 < self.goss_other_rate <= 1.0
@@ -381,7 +374,7 @@ def _validate_multiclass_labels(K: int, y, eval_y=None) -> None:
 
 
 # --------------------------------------------------------------------------
-# fused boosting rounds: one jitted step per round, margins donated
+# boosting rounds: one jitted step per depthwise round, margins donated
 # --------------------------------------------------------------------------
 def _fused_step_key(config: GBDTConfig) -> GBDTConfig:
     """Strip the fields that do not shape the compiled round (loop
@@ -409,22 +402,23 @@ def shrink(tree: TreeArrays, learning_rate: float) -> TreeArrays:
 @functools.lru_cache(maxsize=64)
 def _fused_round_step(config: GBDTConfig, plan: ExecutionPlan, n: int,
                       F: int, n_bins: int, n_eval: Optional[int]):
-    """Compile one boosting round as a single jitted step.
+    """Compile one depthwise boosting round as a single jitted step.
 
-    The step fuses the whole round — gradient statistics, per-round
-    stochastic filters, tree growth (steps ①–④), leaf shrinkage, step-⑤
-    margin refresh and the device-side loss reduction — so the host
-    dispatches once per round and never synchronizes on intermediate
-    values.  Margins (train and eval) are donated where the backend
-    supports donation, so the round updates them in place.  Cached per
-    (``_fused_step_key(config)``, plan, shapes): repeated fits reuse the
-    compiled step.
+    The step fuses the whole round — the round's key
+    ``fold_in(key, t)``, gradient statistics, per-round stochastic
+    filters, tree growth (steps ①–④), leaf shrinkage, step-⑤ margin
+    refresh and the device-side loss reduction — so the host dispatches
+    once per round.  Margins (train and eval) are donated where the
+    backend supports donation, so the round updates them in place.
+    Cached per (``_fused_step_key(config)``, plan, shapes): repeated fits
+    reuse the compiled step.
     """
     loss = losses_mod.get_loss(config.objective, config.n_classes)
     K = loss.n_outputs
     with_eval = n_eval is not None
 
-    def body(margins, y, tkey, codes, codes_cm, is_cat_field):
+    def body(margins, y, key, t, codes, codes_cm, is_cat_field):
+        tkey = jax.random.fold_in(key, t)       # deterministic replay
         g, h, field_mask = _gradients(config, loss, margins, y, tkey, n, F,
                                       K)
         common = dict(depth=config.max_depth, n_bins=n_bins,
@@ -433,6 +427,7 @@ def _fused_round_step(config: GBDTConfig, plan: ExecutionPlan, n: int,
                       gamma=config.gamma,
                       min_child_weight=config.min_child_weight, plan=plan)
         if K is not None:
+            # one class-batched pass grows all K per-class trees
             tree = tree_mod.fit_forest(codes, codes_cm, g.T, h.T, **common)
         else:
             tree = tree_mod.fit_tree(codes, codes_cm, g, h, **common)
@@ -448,9 +443,9 @@ def _fused_round_step(config: GBDTConfig, plan: ExecutionPlan, n: int,
         step = body
         donate = (0,)
     else:
-        def step(margins, ev_margins, y, y_ev, tkey, codes, codes_cm,
+        def step(margins, ev_margins, y, y_ev, key, t, codes, codes_cm,
                  ev_codes, ev_codes_cm, is_cat_field):
-            margins, tree, train_loss = body(margins, y, tkey, codes,
+            margins, tree, train_loss = body(margins, y, key, t, codes,
                                              codes_cm, is_cat_field)
             ev_data = BinnedDataset(ev_codes, ev_codes_cm, is_cat_field,
                                     n_bins, None, None)
@@ -481,14 +476,19 @@ def train(config: GBDTConfig, data: BinnedDataset, y,
     ``plan`` selects the kernel strategies for every step; when omitted it
     is lifted from the config's legacy per-step strategy strings.
 
-    ``recovery`` arms the numerical divergence sentinels: a non-finite
-    loss/margin caught every ``config.log_every`` rounds rolls the fused
-    fit back to the last finite round (learning-rate backoff when the
-    same round diverges twice, bounded by
-    ``recovery.max_divergence_rollbacks``); without a policy the sentinel
-    raises :class:`NumericalDivergenceError` fail-fast.  ``shutdown``
-    (a :class:`repro.resilience.GracefulShutdown`) makes the fit
-    preemption-safe: a delivered signal finishes the in-flight round,
+    A depthwise fit runs each round as one jitted program
+    (:func:`_train_fused`); a lossguide fit, whose grower fetches per
+    node, runs the host loop (:func:`_train_lossguide`).
+
+    ``recovery`` arms the numerical divergence sentinel: a depthwise fit
+    checks loss and margins every ``config.log_every`` rounds and rolls
+    back to the last finite round (learning-rate backoff when the same
+    round diverges twice, bounded by
+    ``recovery.max_divergence_rollbacks``); a lossguide fit raises
+    :class:`NumericalDivergenceError` fail-fast.  Without a policy
+    nothing is checked: a diverging fit returns its NaN-loss model.
+    ``shutdown`` (a :class:`repro.resilience.GracefulShutdown`) makes the
+    fit preemption-safe: a delivered signal finishes the in-flight round,
     commits it, and raises :class:`TrainingInterrupted` carrying the
     partial :class:`TrainResult`.
     """
@@ -510,8 +510,7 @@ def train(config: GBDTConfig, data: BinnedDataset, y,
     if K is not None:
         _validate_multiclass_labels(
             K, y, eval_set[1] if eval_set is not None else None)
-    n, F = data.codes.shape
-    depth = config.max_depth
+    n = data.codes.shape[0]
 
     trees: List[TreeArrays] = []       # one entry per round; multi-class
     history: Dict[str, List[float]] = {"train_loss": []}   # entries: (K,...)
@@ -540,17 +539,33 @@ def train(config: GBDTConfig, data: BinnedDataset, y,
         eval_margins = (jnp.full((eval_set[1].shape[0],), base_margin)
                         if eval_set is not None else None)
 
-    key = jax.random.PRNGKey(config.seed)
-    best_eval, best_round = np.inf, -1
+    fit = (_train_fused if config.grow_policy == "depthwise"
+           else _train_lossguide)
+    return fit(config, plan, data, y, eval_set, trees, margins,
+               eval_margins, base_margin, history,
+               jax.random.PRNGKey(config.seed), callback, verbose,
+               recovery=recovery, shutdown=shutdown)
 
-    if config.fused_rounds:
-        return _train_fused(config, plan, data, y, eval_set, trees, margins,
-                            eval_margins, base_margin, history, key,
-                            callback, verbose, n, F,
-                            recovery=recovery, shutdown=shutdown)
 
+def _interrupted(shutdown, t_idx, trees, result) -> TrainingInterrupted:
+    """The typed resumable error of a shutdown after round ``t_idx``."""
+    return TrainingInterrupted(
+        f"shutdown ({shutdown.signal_name}) after round {t_idx}",
+        rounds_done=len(trees), signal_name=shutdown.signal_name,
+        result=result)
+
+
+def _train_lossguide(config, plan, data, y, eval_set, trees, margins,
+                     eval_margins, base_margin, history, key, callback,
+                     verbose, recovery=None, shutdown=None) -> TrainResult:
+    """The host-driven loop of a lossguide fit: the grower fetches per
+    node, so each step is its own dispatch and the host syncs on the
+    tree, the margins and the loss every round."""
+    loss = losses_mod.get_loss(config.objective, config.n_classes)
+    n, F = data.codes.shape
     step_times = dict.fromkeys(tracing.HOST_LOOP_KEYS + (tracing.SYNC_WAIT,),
                                0.0)
+    best_eval, best_round = np.inf, -1
     start = len(trees)
     for t_idx in range(start, start + config.n_trees):
         with tracing.round_span(t_idx):
@@ -559,25 +574,14 @@ def train(config: GBDTConfig, data: BinnedDataset, y,
                               tracing.BINNING_SPLIT):
                 with tracing.span(tracing.GRADIENTS):
                     g, h, field_mask = _gradients(config, loss, margins, y,
-                                                  tkey, n, F, K)
-                common = dict(depth=depth, n_bins=data.n_bins,
-                              missing_bin=data.missing_bin,
-                              is_cat_field=data.is_categorical,
-                              field_mask=field_mask, lambda_=config.lambda_,
-                              gamma=config.gamma,
-                              min_child_weight=config.min_child_weight,
-                              plan=plan)
-                if K is not None:
-                    # one class-batched pass grows all K per-class trees
-                    tree = tree_mod.fit_forest(data.codes, data.codes_cm,
-                                               g.T, h.T, **common)
-                elif config.grow_policy == "depthwise":
-                    tree = tree_mod.fit_tree(data.codes, data.codes_cm, g, h,
-                                             **common)
-                else:
-                    tree = tree_mod.fit_tree_lossguide(
-                        data.codes, data.codes_cm, g, h,
-                        max_leaves=config.max_leaves, **common)
+                                                  tkey, n, F, None)
+                tree = tree_mod.fit_tree_lossguide(
+                    data.codes, data.codes_cm, g, h,
+                    max_leaves=config.max_leaves, depth=config.max_depth,
+                    n_bins=data.n_bins, missing_bin=data.missing_bin,
+                    is_cat_field=data.is_categorical, field_mask=field_mask,
+                    lambda_=config.lambda_, gamma=config.gamma,
+                    min_child_weight=config.min_child_weight, plan=plan)
                 # shrinkage is folded into the stored leaf values so a tree
                 # is self-contained (predict == sum of tree outputs)
                 tree = shrink(tree, config.learning_rate)
@@ -587,11 +591,7 @@ def train(config: GBDTConfig, data: BinnedDataset, y,
             # step ⑤ — one-tree traversal refreshes margins (and thus g, h)
             with tracing.span(tracing.MARGIN_UPDATE, step_times,
                               tracing.TRAVERSAL):
-                if K is not None:
-                    delta = _predict_forest(tree, data, plan)      # (n, K)
-                else:
-                    delta = _predict_one_tree(tree, data, plan)
-                margins = margins + delta
+                margins = margins + _predict_one_tree(tree, data, plan)
                 with tracing.sync("margins", step_times):
                     margins.block_until_ready()
 
@@ -603,11 +603,8 @@ def train(config: GBDTConfig, data: BinnedDataset, y,
                 history["train_loss"].append(train_loss)
             if eval_set is not None:
                 with tracing.span(tracing.EVAL, step_times, tracing.OTHER):
-                    if K is not None:
-                        ev_delta = _predict_forest(tree, eval_set[0], plan)
-                    else:
-                        ev_delta = _predict_one_tree(tree, eval_set[0], plan)
-                    eval_margins = eval_margins + ev_delta
+                    eval_margins = eval_margins + _predict_one_tree(
+                        tree, eval_set[0], plan)
                     ev = _mean_loss(loss, eval_margins,
                                     jnp.asarray(eval_set[1], jnp.float32))
                     with tracing.sync("eval", step_times):
@@ -629,10 +626,9 @@ def train(config: GBDTConfig, data: BinnedDataset, y,
                                 or t_idx == start + config.n_trees - 1):
                     print(f"[gbdt] tree {t_idx:4d}  "
                           f"train_loss={train_loss:.6f}")
-                # divergence sentinel: the host loop already syncs the loss
-                # each round, so the finiteness check is free; the rollback
-                # machinery lives in the fused/distributed engines — here
-                # the sentinel is fail-fast-but-typed
+                # divergence sentinel: the loss is synced each round, so
+                # the finiteness check is free; lossguide has no rollback,
+                # so the sentinel is fail-fast-but-typed
                 if recovery is not None and not np.isfinite(train_loss):
                     raise NumericalDivergenceError(
                         f"non-finite training loss at round {t_idx}",
@@ -641,15 +637,11 @@ def train(config: GBDTConfig, data: BinnedDataset, y,
                     callback(t_idx, _as_model(trees, base_margin, config,
                                               data.missing_bin, F))
                 if shutdown is not None and shutdown.requested:
-                    partial = TrainResult(
+                    raise _interrupted(shutdown, t_idx, trees, TrainResult(
                         model=_as_model(trees, base_margin, config,
                                         data.missing_bin, F),
                         history=history, step_times=step_times,
-                        stats={"n_rows": n, "interrupted": True})
-                    raise TrainingInterrupted(
-                        f"shutdown ({shutdown.signal_name}) after round "
-                        f"{t_idx}", rounds_done=len(trees),
-                        signal_name=shutdown.signal_name, result=partial)
+                        stats={"n_rows": n, "interrupted": True}))
 
     return TrainResult(model=_as_model(trees, base_margin, config,
                                        data.missing_bin, F),
@@ -659,29 +651,30 @@ def train(config: GBDTConfig, data: BinnedDataset, y,
 
 def _train_fused(config, plan, data, y, eval_set, trees, margins,
                  eval_margins, base_margin, history, key, callback, verbose,
-                 n, F, recovery=None, shutdown=None) -> TrainResult:
-    """The device-resident boosting loop: one jitted dispatch per round.
+                 recovery=None, shutdown=None) -> TrainResult:
+    """The depthwise boosting loop: one jitted dispatch per round.
 
-    The host never synchronizes on per-round values unless it has to —
-    losses stay device scalars, fetched every ``config.log_every`` rounds
-    for verbose logging and once in bulk at the end.  Early stopping is
-    the one per-round consumer: it pulls the eval scalar each round
-    (still a single dispatch per round).  The host clock sees one round
-    program, so wall time lands in a ``fused_rounds`` slot of
-    ``step_times`` (with its ``sync_wait`` sub-total); the round body
-    carries the host loop's device scopes, so a profiler trace still
-    splits the round's device time by step.
+    A round is committed only once it exists: the host reads the round's
+    loss (and eval loss) back, in one ``repro.sync`` span what=loss,
+    before early stopping, the callback or the shutdown question sees
+    the round, and before it dispatches the next.  That read is the
+    round's one sync.  The host clock sees one round program, so wall
+    time lands in a ``fused_rounds`` slot of ``step_times`` (with its
+    ``sync_wait`` sub-total); the round body carries the device scopes,
+    so a profiler trace still splits the round's device time by step.
 
-    Divergence sentinel: every ``config.log_every`` rounds one device-side
-    ``isfinite`` reduction over (loss, margins) is synced to the host.  A
-    trip with a ``recovery`` policy rolls the fit back to the last finite
-    sentinel snapshot and replays — at the ORIGINAL learning rate first
-    (a transient glitch replays bit-equal), backing the rate off by
-    ``recovery.divergence_backoff`` only when the same window diverges
-    twice (``learning_rate`` is part of the step cache key, so the
-    backoff recompiles the round).  Without a policy the sentinel raises
-    :class:`NumericalDivergenceError` fail-fast.
+    Divergence sentinel, armed only by a ``recovery`` policy: every
+    ``config.log_every`` rounds the round's loss and a device-side
+    ``isfinite`` reduction over the margins are checked.  A trip rolls
+    the fit back to the last finite snapshot and replays — at the
+    ORIGINAL learning rate first (a transient glitch replays bit-equal),
+    backing the rate off by ``recovery.divergence_backoff`` only when
+    the same window diverges twice (``learning_rate`` is part of the
+    step cache key, so the backoff recompiles the round).  Once
+    ``recovery.max_divergence_rollbacks`` are spent the trip raises
+    :class:`NumericalDivergenceError`.
     """
+    n, F = data.codes.shape
     live = config                      # LR backoff replaces this copy only
     n_eval = None if eval_set is None else int(eval_set[1].shape[0])
     step = _fused_round_step(_fused_step_key(live), plan, n, F,
@@ -689,81 +682,73 @@ def _train_fused(config, plan, data, y, eval_set, trees, margins,
     y_ev = (jnp.asarray(eval_set[1], jnp.float32)
             if eval_set is not None else None)
     step_times = {tracing.FUSED_ROUNDS: 0.0, tracing.SYNC_WAIT: 0.0}
-    train_dev: List[jax.Array] = []
-    eval_dev: List[jax.Array] = []
     best_eval, best_round = np.inf, -1
     rstats = {"divergence_rollbacks": 0}
     t_loop = time.perf_counter()
     start = len(trees)
     end = start + config.n_trees
 
-    def _flush_history():
-        # one bulk fetch materializes the whole loss trajectory
-        with tracing.sync("history", step_times):
-            history["train_loss"].extend(float(v)
-                                         for v in jax.device_get(train_dev))
-            if eval_set is not None:
-                history["eval_loss"].extend(float(v)
-                                            for v in jax.device_get(eval_dev))
-        step_times[tracing.FUSED_ROUNDS] = time.perf_counter() - t_loop
-
     def _snap(t_next):
         """Host copy of the resumable loop state (taken only at finite
         sentinel checks, so a rollback always lands on finite state)."""
         with tracing.sync("snapshot", step_times):
-            return {"t": t_next, "trees": len(trees), "dev": len(train_dev),
+            return {"t": t_next, "trees": len(trees),
+                    "hist": len(history["train_loss"]),
                     "margins": np.asarray(margins),
                     "eval": (None if eval_margins is None
                              else np.asarray(eval_margins)),
                     "best": (best_eval, best_round)}
 
-    snap = _snap(start)
+    snap = _snap(start) if recovery is not None else None
     diverged_at = -1                   # sentinel window of the last trip
     t_idx = start
     stop_early = False
     while t_idx < end and not stop_early:
         with tracing.round_span(t_idx):
-            tkey = jax.random.fold_in(key, t_idx)   # host loop's stream
             with tracing.span(tracing.DISPATCH):
                 if eval_set is None:
-                    margins, tree, tl = step(margins, y, tkey, data.codes,
-                                             data.codes_cm,
+                    margins, tree, tl = step(margins, y, key, t_idx,
+                                             data.codes, data.codes_cm,
                                              data.is_categorical)
+                    fetched = (tl,)
                 else:
                     margins, eval_margins, tree, tl, ev = step(
-                        margins, eval_margins, y, y_ev, tkey, data.codes,
-                        data.codes_cm, eval_set[0].codes,
+                        margins, eval_margins, y, y_ev, key, t_idx,
+                        data.codes, data.codes_cm, eval_set[0].codes,
                         eval_set[0].codes_cm, data.is_categorical)
+                    fetched = (tl, ev)
+            with tracing.sync("loss", step_times):
+                fetched = [float(v) for v in jax.device_get(fetched)]
             with tracing.span(tracing.COMMIT):
-                if eval_set is not None:
-                    eval_dev.append(ev)
                 trees.append(tree)
-                train_dev.append(tl)
-                if (eval_set is not None
-                        and config.early_stopping_rounds is not None):
-                    with tracing.sync("eval", step_times):
-                        ev_f = float(ev)        # the one per-round sync
+                history["train_loss"].append(fetched[0])
+                if eval_set is not None:
+                    ev_f = fetched[1]
+                    history["eval_loss"].append(ev_f)
                     if ev_f < best_eval - 1e-12:
                         best_eval, best_round = ev_f, t_idx
-                    if t_idx - best_round >= config.early_stopping_rounds:
+                    if (config.early_stopping_rounds is not None
+                            and t_idx - best_round
+                            >= config.early_stopping_rounds):
                         if verbose:
                             print(f"[gbdt] early stop at tree {t_idx} "
                                   f"(best {best_round}: {best_eval:.6f})")
                         stop_early = True
                 if verbose and (t_idx % config.log_every == 0
                                 or t_idx == end - 1):
-                    with tracing.sync("loss", step_times):
-                        tl_f = float(tl)
-                    print(f"[gbdt] tree {t_idx:4d}  train_loss={tl_f:.6f}")
+                    print(f"[gbdt] tree {t_idx:4d}  "
+                          f"train_loss={fetched[0]:.6f}")
 
-                # ---- divergence sentinel (one fused device reduction + sync)
-                if (t_idx % config.log_every == 0 or t_idx == end - 1
+                # ---- divergence sentinel (armed by a recovery policy)
+                if recovery is not None and (
+                        t_idx % config.log_every == 0 or t_idx == end - 1
                         or stop_early):
-                    finite = jnp.isfinite(tl) & jnp.all(jnp.isfinite(margins))
-                    with tracing.sync("sentinel", step_times):
-                        finite = bool(finite)
+                    finite = bool(np.isfinite(fetched[0]))
+                    if finite:
+                        with tracing.sync("sentinel", step_times):
+                            finite = bool(jnp.all(jnp.isfinite(margins)))
                     if not finite:
-                        if (recovery is None or rstats["divergence_rollbacks"]
+                        if (rstats["divergence_rollbacks"]
                                 >= recovery.max_divergence_rollbacks):
                             raise NumericalDivergenceError(
                                 f"non-finite loss/margins at round {t_idx}",
@@ -771,8 +756,8 @@ def _train_fused(config, plan, data, y, eval_set, trees, margins,
                         rstats["divergence_rollbacks"] += 1
                         _metrics.record("recoveries")
                         del trees[snap["trees"]:]
-                        del train_dev[snap["dev"]:]
-                        del eval_dev[snap["dev"]:]
+                        for trail in history.values():
+                            del trail[snap["hist"]:]
                         margins = jnp.asarray(snap["margins"])
                         eval_margins = (None if snap["eval"] is None
                                         else jnp.asarray(snap["eval"]))
@@ -803,24 +788,19 @@ def _train_fused(config, plan, data, y, eval_set, trees, margins,
                     callback(t_idx, _as_model(trees, base_margin, config,
                                               data.missing_bin, F))
                 if shutdown is not None and shutdown.requested:
-                    _flush_history()
-                    partial = TrainResult(
+                    step_times[tracing.FUSED_ROUNDS] = (time.perf_counter()
+                                                        - t_loop)
+                    raise _interrupted(shutdown, t_idx, trees, TrainResult(
                         model=_as_model(trees, base_margin, config,
                                         data.missing_bin, F),
                         history=history, step_times=step_times,
-                        stats={"n_rows": n, "fused_rounds": True,
-                               "interrupted": True, **rstats})
-                    raise TrainingInterrupted(
-                        f"shutdown ({shutdown.signal_name}) after round "
-                        f"{t_idx}", rounds_done=len(trees),
-                        signal_name=shutdown.signal_name, result=partial)
+                        stats={"n_rows": n, "interrupted": True, **rstats}))
         t_idx += 1
-    _flush_history()
-    jax.block_until_ready(margins)
+    step_times[tracing.FUSED_ROUNDS] = time.perf_counter() - t_loop
     return TrainResult(model=_as_model(trees, base_margin, config,
                                        data.missing_bin, F),
                        history=history, step_times=step_times,
-                       stats={"n_rows": n, "fused_rounds": True, **rstats})
+                       stats={"n_rows": n, **rstats})
 
 
 def _as_model(trees, base_margin, config, missing_bin, F) -> GBDTModel:
@@ -980,8 +960,8 @@ def train_streaming(config: GBDTConfig, source, binner, y, *,
     while node ids stay maintained for every record, so margins (and the
     next round's gradients) remain exact.
 
-    ``config.fused_rounds`` is ignored here: every round is a host-driven
-    chunk pipeline by construction.  ``plan.hist_subtraction`` applies —
+    Every round is a host-driven chunk pipeline by construction, not one
+    program as in :func:`train`.  ``plan.hist_subtraction`` applies —
     levels > 0 accumulate only smaller-child statistics per chunk and
     derive the sibling histograms once per level.
     """

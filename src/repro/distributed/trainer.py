@@ -9,8 +9,8 @@ Everything downstream of the reduction (step ② split decisions, the tree
 tables) is replicated math on the psum'd histogram, so every shard grows
 the *same* tree; step ③ partitions each shard's records locally.
 
-A whole boosting round stays on-device per host (the ``fused_rounds``
-semantics): gradients, the per-round stochastic filters, the sharded
+A whole boosting round stays on-device per host, as in the single-device
+depthwise fit (``core.gbdt.train``): gradients, the per-round stochastic filters, the sharded
 grower, leaf shrinkage, the margin refresh and the loss reduction compile
 into one jitted step dispatched once per round.
 
@@ -292,7 +292,7 @@ def _grow_forest_sharded(mesh: Mesh, da: Tuple[str, ...], *, depth: int,
 
 
 # --------------------------------------------------------------------------
-# one boosting round as a single jitted dispatch (fused_rounds semantics)
+# one boosting round as a single jitted dispatch (as core.gbdt.train's)
 # --------------------------------------------------------------------------
 @functools.lru_cache(maxsize=32)
 def _distributed_round_step(config: GBDTConfig, plan: ExecutionPlan,

@@ -32,12 +32,12 @@ GRADIENTS = "gradients"          # inside grow: g, h and the round's filters
 MARGIN_UPDATE = "margin_update"  # step ⑤, to the margins' sync
 LOSS = "loss"                    # the training loss, fetched
 EVAL = "eval"                    # the eval set's margins and loss, fetched
-DISPATCH = "dispatch"            # the fused round's one jitted call
+DISPATCH = "dispatch"            # the depthwise round's one jitted call
 COMMIT = "commit"                # early stop, logging, sentinel, callback,
 #                                  the shutdown question
 SYNC = "sync"                    # the host blocked on the device; meta
 #                                  ``what``: tree, margins, loss, eval,
-#                                  sentinel, history or snapshot
+#                                  sentinel or snapshot
 
 # device scopes (the name stack of the ops traced under them)
 STEP1 = "step1_histogram"        # per level: step1_histogram/level<l>
@@ -50,7 +50,7 @@ STEP5 = "step5_traversal"
 BINNING_SPLIT = "binning_split"  # the grow span
 TRAVERSAL = "traversal"          # the margin_update span
 OTHER = "other"                  # the loss and eval spans
-FUSED_ROUNDS = "fused_rounds"    # the fused loop, start to last fetch
+FUSED_ROUNDS = "fused_rounds"    # the depthwise loop, start to end
 SYNC_WAIT = "sync_wait"          # the sync spans: a sub-total, not a phase
 HOST_LOOP_KEYS = (BINNING_SPLIT, TRAVERSAL, OTHER)
 

@@ -210,6 +210,17 @@ def test_estimator_bundle_roundtrip(data, fitted, tmp_path):
                                   np.asarray(fitted.predict(X)))
 
 
+def test_bundle_naming_the_removed_fused_rounds_param_loads(fitted):
+    """Bundles saved while a round had an opt-in ``fused_rounds`` knob
+    name it among their params; they still load."""
+    from repro.api import serialize
+    arrays, meta = serialize.pack(fitted)
+    meta["estimator"]["params"]["fused_rounds"] = False
+    est = serialize.unpack(arrays, meta)
+    assert isinstance(est, BoosterRegressor)
+    assert est.get_params() == fitted.get_params()
+
+
 def test_pipeline_and_model_share_bundle_format(data, fitted, tmp_path):
     X, _, _ = data
     pipe = fitted.to_pipeline()

@@ -438,13 +438,14 @@ def test_divergence_sentinel_raises_typed():
 
 
 def test_divergence_fused_rollback_budget_exhausts():
-    """The fused engine rolls back and halves the LR on a divergence trip;
-    a persistently-diverging config exhausts max_divergence_rollbacks and
-    the typed error propagates (never an unbounded retry loop)."""
+    """The depthwise round loop rolls back and halves the LR on a
+    divergence trip; a persistently-diverging config exhausts
+    max_divergence_rollbacks and the typed error propagates (never an
+    unbounded retry loop)."""
     X, y = _xy(64)
     with pytest.raises(NumericalDivergenceError):
         BoosterRegressor(n_trees=4, max_depth=2, learning_rate=1e30,
-                         fused_rounds=True, log_every=1).fit(
+                         log_every=1).fit(
             X, y, recovery=RecoveryPolicy(max_divergence_rollbacks=2))
 
 
@@ -461,13 +462,14 @@ def test_divergence_without_recovery_is_legacy_silent():
 # PR 10 — graceful shutdown: typed resumable interrupts, resume equality
 # --------------------------------------------------------------------------
 def test_shutdown_interrupts_host_and_fused_and_resumes_bit_equal(tmp_path):
-    """sd.request() after round 2 interrupts BOTH single-process engines
+    """sd.request() after round 2 interrupts BOTH single-process round
+    loops (lossguide's host loop and the depthwise one-program round)
     after the commit; the partial model stays fitted state and a resume
     from the checkpoint lands on the bit-identical final ensemble."""
     X, y = _xy(256)
-    for i, fused in enumerate((False, True)):
+    for i, policy in enumerate(("lossguide", "depthwise")):
         kw = dict(n_trees=6, max_depth=3, max_bins=32, seed=3,
-                  fused_rounds=fused)
+                  grow_policy=policy)
         gold = BoosterRegressor(**kw).fit(X, y)
         ckdir = str(tmp_path / f"ck{i}")
         est = BoosterRegressor(**kw)

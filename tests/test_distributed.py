@@ -106,10 +106,11 @@ def test_train_distributed_matches_single_device_regression():
     """K=1 parity across shard counts {1, 2, 8}: dyadic targets (multiples
     of 0.25, n a power of two, squared-error h=1) make every round-0
     histogram cell exactly representable, so the first tree must be
-    BIT-equal for every shard count; D=1 must be bit-equal to the fused
-    single-device trainer for the WHOLE trajectory (trees and losses);
-    every D must match the per-op trainer within the documented
-    float-tolerance contract (identical structure, leaf values ~1e-6)."""
+    BIT-equal for every shard count; D=1 must be bit-equal to the
+    single-device trainer (one program a round, like the sharded step)
+    for the WHOLE trajectory (trees and losses); every D must match it
+    within the documented float-tolerance contract (identical structure,
+    leaf values ~1e-6)."""
     out = _run_with_devices(r"""
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import GBDTConfig, bin_dataset, train
@@ -122,8 +123,6 @@ y = (rng.integers(-8, 9, n) * 0.25).astype(np.float32)   # dyadic targets
 data = bin_dataset(X, max_bins=32)
 cfg = GBDTConfig(n_trees=4, max_depth=4, hist_strategy="scatter")
 ref = train(cfg, data, y)
-fused = train(GBDTConfig(n_trees=4, max_depth=4, hist_strategy="scatter",
-                         fused_rounds=True), data, y)
 pref = np.asarray(ref.model.predict(data))
 cfg1 = GBDTConfig(n_trees=1, max_depth=4, hist_strategy="scatter")
 tree0 = train(cfg1, data, y).model.trees
@@ -138,13 +137,13 @@ for D in (1, 2, 8):
                                       err_msg=f"round0 D={D} {nm}")
     if D == 1:
         # one shard reassociates nothing: the full trajectory is
-        # bit-equal to the fused trainer (same one-jit round program)
-        for a, b, nm in zip(res.model.trees, fused.model.trees,
+        # bit-equal to the single-device trainer (same one-jit round)
+        for a, b, nm in zip(res.model.trees, ref.model.trees,
                             tree0._fields):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                           err_msg=f"D=1 fused {nm}")
-        assert res.history["train_loss"] == fused.history["train_loss"]
-    # full trajectory vs the per-op trainer: same structure, leaf values
+        assert res.history["train_loss"] == ref.history["train_loss"]
+    # full trajectory vs the single-device trainer: same structure, leaves
     # within the float contract (FMA/psum reassociation)
     for nm in ("feature", "threshold", "is_cat", "default_left"):
         np.testing.assert_array_equal(
@@ -180,10 +179,6 @@ data = bin_dataset(X, max_bins=32)
 cfg = GBDTConfig(n_trees=3, max_depth=3, objective="multi:softmax",
                  n_classes=3, hist_strategy="scatter")
 ref = train(cfg, data, y, eval_set=(data, y))
-fused = train(GBDTConfig(n_trees=3, max_depth=3, objective="multi:softmax",
-                         n_classes=3, hist_strategy="scatter",
-                         fused_rounds=True), data, y, eval_set=(data, y))
-pfused = np.asarray(fused.model.predict_margin(data.codes))
 pref = np.asarray(ref.model.predict_margin(data.codes))
 for D in (1, 2, 8):
     mesh = data_parallel_mesh(jax.devices()[:D])
@@ -195,9 +190,9 @@ for D in (1, 2, 8):
             err_msg=f"D={D} {nm}")
     p = np.asarray(res.model.predict_margin(data.codes))
     if D == 1:   # one shard: bit-equal to the fused one-jit round program
-        for a, b in zip(res.model.trees, fused.model.trees):
+        for a, b in zip(res.model.trees, ref.model.trees):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        np.testing.assert_array_equal(p, pfused)
+        np.testing.assert_array_equal(p, pref)
     np.testing.assert_allclose(p, pref, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(res.history["eval_loss"],
                                ref.history["eval_loss"],

@@ -7,12 +7,18 @@ Split decisions argmax over well-separated gains, so trees come out
 *structurally identical* on generic data; leaf values (segment sums over
 the same final partition) match to float tolerance.
 """
-import numpy as np
+import dataclasses
+
+import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
+from repro import tracing
 from repro.api.plan import HIST_STRATEGIES, ExecutionPlan
 from repro.core import GBDTConfig, bin_dataset, train
+from repro.core import gbdt as gbdt_mod
+from repro.core import losses as losses_mod
 from repro.core import splits as splits_mod
 from repro.core import tree as tree_mod
 from repro.core.binning import BinnedDataset
@@ -209,7 +215,7 @@ def test_accumulate_histogram_rebinding():
 
 
 # --------------------------------------------------------------------------
-# fused boosting rounds: trajectory parity vs the host-driven loop
+# fused boosting rounds: trajectory parity vs a host-driven round loop
 # --------------------------------------------------------------------------
 @pytest.fixture(scope="module")
 def boost_data():
@@ -226,6 +232,60 @@ def boost_data():
     return sub(slice(0, 1400)), y[:1400], sub(slice(1400, None)), y[1400:]
 
 
+def _host_rounds(config, data, y, eval_set=None, plan=None):
+    """The oracle: one round at a time from the host, each step its own
+    dispatch, over the public grower, ``shrink`` and the step-⑤
+    traversal.  Returns (model, history) as ``train`` would."""
+    plan = (plan or ExecutionPlan.from_config(config)).resolved()
+    loss = losses_mod.get_loss(config.objective, config.n_classes)
+    K = loss.n_outputs
+    y = jnp.asarray(y, jnp.float32)
+    n, F = data.codes.shape
+    if K is not None:
+        base = np.asarray(loss.base_margin(y), np.float32)
+        start = lambda rows: jnp.broadcast_to(jnp.asarray(base), (rows, K))
+        grow, predict = tree_mod.fit_forest, gbdt_mod._predict_forest
+    else:
+        base = float(loss.base_margin(y))
+        start = lambda rows: jnp.full((rows,), base, jnp.float32)
+        grow, predict = tree_mod.fit_tree, gbdt_mod._predict_one_tree
+    margins = start(n)
+    history = {"train_loss": []}
+    if eval_set is not None:
+        ev_data, ev_y = eval_set[0], jnp.asarray(eval_set[1], jnp.float32)
+        ev_margins = start(ev_y.shape[0])
+        history["eval_loss"] = []
+    key = jax.random.PRNGKey(config.seed)
+    trees, best, best_round = [], np.inf, -1
+    for t in range(config.n_trees):
+        g, h, field_mask = gbdt_mod._gradients(
+            config, loss, margins, y, jax.random.fold_in(key, t), n, F, K)
+        if K is not None:
+            g, h = g.T, h.T
+        tree = gbdt_mod.shrink(
+            grow(data.codes, data.codes_cm, g, h, field_mask=field_mask,
+                 depth=config.max_depth, n_bins=data.n_bins,
+                 missing_bin=data.missing_bin,
+                 is_cat_field=data.is_categorical, lambda_=config.lambda_,
+                 gamma=config.gamma,
+                 min_child_weight=config.min_child_weight, plan=plan),
+            config.learning_rate)
+        trees.append(tree)
+        margins = margins + predict(tree, data, plan)
+        history["train_loss"].append(float(jnp.mean(loss.value(margins, y))))
+        if eval_set is not None:
+            ev_margins = ev_margins + predict(tree, ev_data, plan)
+            ev = float(jnp.mean(loss.value(ev_margins, ev_y)))
+            history["eval_loss"].append(ev)
+            if ev < best - 1e-12:
+                best, best_round = ev, t
+            if (config.early_stopping_rounds is not None
+                    and t - best_round >= config.early_stopping_rounds):
+                break
+    return (gbdt_mod._as_model(trees, base, config, data.missing_bin, F),
+            history)
+
+
 @pytest.mark.parametrize("kw", [
     dict(),
     dict(subsample=0.7, colsample_bytree=0.7),
@@ -240,15 +300,14 @@ def test_fused_rounds_trajectory_parity(boost_data, kw):
     tr, ytr, te, _ = boost_data
     if kw.get("objective") == "binary:logistic":
         ytr = (np.asarray(ytr) > np.median(ytr)).astype(np.float32)
-    a = train(GBDTConfig(n_trees=6, max_depth=4, hist_strategy="scatter",
-                         **kw), tr, ytr)
-    b = train(GBDTConfig(n_trees=6, max_depth=4, hist_strategy="scatter",
-                         fused_rounds=True, **kw), tr, ytr)
-    for fa, fb in zip(a.model.trees[:4], b.model.trees[:4]):
+    cfg = GBDTConfig(n_trees=6, max_depth=4, hist_strategy="scatter", **kw)
+    a_model, a_hist = _host_rounds(cfg, tr, ytr)
+    b = train(cfg, tr, ytr)
+    for fa, fb in zip(a_model.trees[:4], b.model.trees[:4]):
         np.testing.assert_array_equal(np.asarray(fa)[0], np.asarray(fb)[0])
-    np.testing.assert_allclose(a.history["train_loss"],
+    np.testing.assert_allclose(a_hist["train_loss"],
                                b.history["train_loss"], rtol=1e-4)
-    np.testing.assert_allclose(np.asarray(a.model.predict(te)),
+    np.testing.assert_allclose(np.asarray(a_model.predict(te)),
                                np.asarray(b.model.predict(te)),
                                rtol=1e-3, atol=1e-4)
 
@@ -258,11 +317,11 @@ def test_fused_rounds_goss_losses_match(boost_data):
     fused and host loops can flip near-ties in that ranking, so structural
     equality is not guaranteed — the loss trajectories still agree."""
     tr, ytr, _, _ = boost_data
-    kw = dict(n_trees=6, max_depth=4, hist_strategy="scatter",
-              goss_top_rate=0.2, goss_other_rate=0.2)
-    a = train(GBDTConfig(**kw), tr, ytr)
-    b = train(GBDTConfig(fused_rounds=True, **kw), tr, ytr)
-    np.testing.assert_allclose(a.history["train_loss"],
+    cfg = GBDTConfig(n_trees=6, max_depth=4, hist_strategy="scatter",
+                     goss_top_rate=0.2, goss_other_rate=0.2)
+    _, a_hist = _host_rounds(cfg, tr, ytr)
+    b = train(cfg, tr, ytr)
+    np.testing.assert_allclose(a_hist["train_loss"],
                                b.history["train_loss"], rtol=1e-4)
 
 
@@ -270,51 +329,76 @@ def test_fused_rounds_multiclass_parity(boost_data):
     tr, ytr, _, _ = boost_data
     y3 = np.digitize(np.asarray(ytr),
                      np.quantile(np.asarray(ytr), [0.33, 0.66]))
-    kw = dict(n_trees=4, max_depth=3, objective="multi:softmax",
-              n_classes=3, hist_strategy="scatter")
-    a = train(GBDTConfig(**kw), tr, y3.astype(np.float32))
-    b = train(GBDTConfig(fused_rounds=True, **kw), tr,
-              y3.astype(np.float32))
-    for fa, fb in zip(a.model.trees[:4], b.model.trees[:4]):
+    cfg = GBDTConfig(n_trees=4, max_depth=3, objective="multi:softmax",
+                     n_classes=3, hist_strategy="scatter")
+    a_model, a_hist = _host_rounds(cfg, tr, y3.astype(np.float32))
+    b = train(cfg, tr, y3.astype(np.float32))
+    for fa, fb in zip(a_model.trees[:4], b.model.trees[:4]):
         # round 0 (the first K class trees) sees bit-identical inputs
         np.testing.assert_array_equal(np.asarray(fa)[:3], np.asarray(fb)[:3])
-    np.testing.assert_allclose(a.history["train_loss"],
+    np.testing.assert_allclose(a_hist["train_loss"],
                                b.history["train_loss"], rtol=1e-4)
 
 
 def test_fused_rounds_early_stopping_matches(boost_data):
     tr, ytr, te, yte = boost_data
-    kw = dict(n_trees=40, max_depth=5, learning_rate=0.5,
-              early_stopping_rounds=3, hist_strategy="scatter")
-    a = train(GBDTConfig(**kw), tr, ytr, eval_set=(te, jnp.asarray(yte)))
-    b = train(GBDTConfig(fused_rounds=True, **kw), tr, ytr,
-              eval_set=(te, jnp.asarray(yte)))
-    assert a.model.n_trees == b.model.n_trees
+    cfg = GBDTConfig(n_trees=40, max_depth=5, learning_rate=0.5,
+                     early_stopping_rounds=3, hist_strategy="scatter")
+    a_model, a_hist = _host_rounds(cfg, tr, ytr, eval_set=(te, yte))
+    b = train(cfg, tr, ytr, eval_set=(te, jnp.asarray(yte)))
+    assert a_model.n_trees == b.model.n_trees
     assert len(b.history["eval_loss"]) == b.model.n_trees
+    np.testing.assert_allclose(a_hist["eval_loss"], b.history["eval_loss"],
+                               rtol=1e-4)
     assert "fused_rounds" in b.step_times
 
 
-def test_fused_plus_subtraction_end_to_end(boost_data):
-    """The acceptance path: fused rounds + hist_subtraction together
-    reproduce the baseline trainer's trajectory (same float-tolerance
-    contract as each optimization alone)."""
+def test_fused_rounds_init_model_parity(boost_data):
+    """A fit continued from ``init_model`` keeps the round index (and so
+    the RNG stream) and lands on the uninterrupted trajectory."""
     tr, ytr, te, _ = boost_data
-    plan = ExecutionPlan(hist_strategy="scatter",
-                         hist_subtraction=True).resolved()
-    a = train(GBDTConfig(n_trees=6, max_depth=5, hist_strategy="scatter"),
-              tr, ytr)
-    b = train(GBDTConfig(n_trees=6, max_depth=5, fused_rounds=True),
-              tr, ytr, plan=plan)
-    np.testing.assert_allclose(a.history["train_loss"],
+    cfg = GBDTConfig(n_trees=6, max_depth=4, hist_strategy="scatter",
+                     subsample=0.8)
+    a_model, a_hist = _host_rounds(cfg, tr, ytr)
+    half = dataclasses.replace(cfg, n_trees=3)
+    first = train(half, tr, ytr)
+    b = train(half, tr, ytr, init_model=first.model)
+    assert b.model.n_trees == 6
+    np.testing.assert_allclose(a_hist["train_loss"][3:],
                                b.history["train_loss"], rtol=1e-4)
-    np.testing.assert_allclose(np.asarray(a.model.predict(te)),
+    np.testing.assert_allclose(np.asarray(a_model.predict(te)),
                                np.asarray(b.model.predict(te)),
                                rtol=1e-3, atol=1e-4)
 
 
-def test_fused_rounds_rejects_lossguide():
-    with pytest.raises(ValueError, match="fused_rounds"):
-        GBDTConfig(fused_rounds=True, grow_policy="lossguide")
+def test_fused_plus_subtraction_end_to_end(boost_data):
+    """The acceptance path: fused rounds + hist_subtraction together
+    reproduce the host loop's direct-histogram trajectory (same
+    float-tolerance contract as each optimization alone)."""
+    tr, ytr, te, _ = boost_data
+    plan = ExecutionPlan(hist_strategy="scatter",
+                         hist_subtraction=True).resolved()
+    a_model, a_hist = _host_rounds(
+        GBDTConfig(n_trees=6, max_depth=5, hist_strategy="scatter"), tr, ytr)
+    b = train(GBDTConfig(n_trees=6, max_depth=5), tr, ytr, plan=plan)
+    np.testing.assert_allclose(a_hist["train_loss"],
+                               b.history["train_loss"], rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(a_model.predict(te)),
+                               np.asarray(b.model.predict(te)),
+                               rtol=1e-3, atol=1e-4)
+
+
+def test_grow_policy_picks_the_round_loop(boost_data):
+    """Depthwise fits run one program a round; lossguide alone keeps the
+    host loop, and each reports its own ``step_times`` keys."""
+    tr, ytr, _, _ = boost_data
+    kw = dict(n_trees=2, max_depth=3)
+    depthwise = train(GBDTConfig(**kw), tr, ytr).step_times
+    lossguide = train(GBDTConfig(grow_policy="lossguide", **kw),
+                      tr, ytr).step_times
+    assert set(depthwise) == {tracing.FUSED_ROUNDS, tracing.SYNC_WAIT}
+    assert set(lossguide) == set(tracing.HOST_LOOP_KEYS) | {
+        tracing.SYNC_WAIT}
 
 
 def test_streaming_subtraction_trajectory_parity():

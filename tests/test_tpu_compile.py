@@ -165,3 +165,41 @@ def test_grower_kernels_keep_their_names_under_step_scopes(one_chip):
         assert sorted(re.search(rf"/{scope}/level(\d)/", s).group(1)
                       for s in stacks) == [str(lv) for lv in range(depth)]
     assert f"/{tracing.STEP2}/level{depth - 1}/" in text
+
+
+def test_fused_round_keeps_kernel_names_under_step_scopes(one_chip):
+    """The whole depthwise round at higgs widths, as one program for a
+    v5e: each level's histogram and partition kernels and the step-⑤
+    traversal kernel stay ops named after their kernel, under their step
+    scope, inside the one round program a trace reads."""
+    from repro import tracing
+    from repro.core import gbdt as gbdt_mod
+    depth, n, fields, bins = 6, 65536, 28, 256
+    plan = ExecutionPlan(hist_strategy="pallas_grouped",
+                         partition_strategy="pallas",
+                         traversal_strategy="pallas",
+                         interpret=False).resolved()
+    cfg = GBDTConfig(n_trees=1, max_depth=depth,
+                     objective="binary:logistic")
+    step = gbdt_mod._fused_round_step(gbdt_mod._fused_step_key(cfg), plan,
+                                      n, fields, bins, None)
+    stat = _spec(one_chip, (n,), jnp.float32)
+    text = step.lower(
+        stat, stat, _spec(one_chip, (2,), jnp.uint32),
+        _spec(one_chip, (), jnp.int32),
+        _spec(one_chip, (n, fields), jnp.uint8),
+        _spec(one_chip, (fields, n), jnp.uint8),
+        _spec(one_chip, (fields,), jnp.bool_)).compile().as_text()
+    kernels = re.findall(r"%([\w.]+) = [^\n]*"
+                         r'custom_call_target="tpu_custom_call"[^\n]*'
+                         r'op_name="([^"]*)"', text)
+    for kernel, scope in (("histogram_pallas", tracing.STEP1),
+                          ("partition_pallas", tracing.STEP3)):
+        stacks = [stack for name, stack in kernels
+                  if re.fullmatch(rf"{kernel}\.\d+", name)]
+        assert sorted(re.search(rf"/{scope}/level(\d)/", s).group(1)
+                      for s in stacks) == [str(lv) for lv in range(depth)]
+    traversal_ops = [stack for name, stack in kernels
+                     if re.fullmatch(r"predict_ensemble_pallas\.\d+", name)]
+    assert len(traversal_ops) == 1
+    assert f"/{tracing.STEP5}/" in traversal_ops[0]
